@@ -1,4 +1,5 @@
-"""Qwen2-1.5B [arXiv:2407.10671] — dense GQA (kv=2), QKV bias."""
+"""Qwen2-1.5B [arXiv:2407.10671] — dense GQA (kv=2), QKV bias, tied
+embeddings (``tie_word_embeddings: true`` in the published config)."""
 
 from repro.models.config import ModelConfig
 
@@ -12,4 +13,5 @@ CONFIG = ModelConfig(
     d_ff=8960,
     vocab=151936,
     qkv_bias=True,
+    tie_embeddings=True,
 )

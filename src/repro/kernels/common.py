@@ -10,24 +10,12 @@ from __future__ import annotations
 import functools
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
 
 
 @functools.cache
 def default_interpret() -> bool:
     """Interpret unless a real TPU backend is present."""
     return jax.default_backend() != "tpu"
-
-
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams around 0.5;
-# resolve whichever this jax ships so the kernels compile on both.
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
-
-def compiler_params(**kwargs):
-    """Version-portable ``pltpu.CompilerParams`` constructor."""
-    return _COMPILER_PARAMS_CLS(**kwargs)
 
 
 def cdiv(a: int, b: int) -> int:

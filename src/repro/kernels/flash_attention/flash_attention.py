@@ -40,8 +40,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import compiler_params
-
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 # lse stand-in for fully-masked rows: large positive so exp(s - lse)
 # underflows to exactly 0 in the backward rebuild
@@ -117,7 +115,9 @@ def _fwd_kernel(kv_len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         safe = jnp.where(l == 0.0, 1.0, l)           # fully-masked rows
         o_ref[0] = (acc_scr[...] / safe).astype(o_ref.dtype)
         lse = m_scr[...] + jnp.log(safe)
-        lse_ref[0] = jnp.where(l == 0.0, FULLY_MASKED_LSE, lse)[:, 0]
+        # (bq, 1) column -> the (1, bq) lane-major row the residual is
+        # stored as
+        lse_ref[0] = jnp.where(l == 0.0, FULLY_MASKED_LSE, lse).T
 
 
 def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -174,7 +174,7 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         out_specs=[
             pl.BlockSpec((1, block_q, dv), lambda b, i, j, ref: (b, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q), lambda b, i, j, ref: (b, i),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j, ref: (b, 0, i),
                          memory_space=pltpu.VMEM),
         ],
         scratch_shapes=[
@@ -188,14 +188,14 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(kv_len.astype(jnp.int32), q, k, v)
-    return o, lse
+    return o, lse[:, 0]
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -236,13 +236,13 @@ def _dq_kernel(kv_len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                 preferred_element_type=jnp.float32) * scale
         valid = _score_mask(qi, kj, kv_len, block_q, block_k, causal)
         s = jnp.where(valid, s, DEFAULT_MASK_VALUE)
-        lse = lse_ref[0][:, None]                    # (bq, 1)
+        lse = lse_ref[0].T                           # (bq, 1)
         p = jnp.where(valid, jnp.exp(s - lse), 0.0)  # (bq, bk)
         do = do_ref[0].astype(jnp.float32)           # (bq, dv)
         v = v_ref[0].astype(jnp.float32)             # (bk, dv)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        delta = delta_ref[0][:, None]                # (bq, 1)
+        delta = delta_ref[0].T                       # (bq, 1)
         ds = p * (dp - delta) * scale                # (bq, bk)
         acc_scr[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
@@ -282,7 +282,7 @@ def _dkv_kernel(kv_len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                 preferred_element_type=jnp.float32) * scale
         valid = _score_mask(qi, kj, kv_len, block_q, block_k, causal)
         s = jnp.where(valid, s, DEFAULT_MASK_VALUE)
-        lse = lse_ref[0][:, None]
+        lse = lse_ref[0].T
         p = jnp.where(valid, jnp.exp(s - lse), 0.0)  # (bq, bk)
         do = do_ref[0].astype(jnp.float32)           # (bq, dv)
         v = v_ref[0].astype(jnp.float32)             # (bk, dv)
@@ -291,7 +291,7 @@ def _dkv_kernel(kv_len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        delta = delta_ref[0][:, None]
+        delta = delta_ref[0].T
         ds = p * (dp - delta) * scale
         dk_scr[...] += jax.lax.dot_general(          # ds^T @ q -> (bk, d)
             ds, q, (((0,), (0,)), ((), ())),
@@ -331,6 +331,10 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if kv_len is None:
         kv_len = jnp.full((bh,), skv, jnp.int32)
     kv_len = kv_len.astype(jnp.int32)
+    # per-row residuals ride as (bh, 1, sq) so a (1, 1, block_q) block
+    # meets the TPU tiling rule (a (1, block_q) block of (bh, sq) cannot)
+    lse = lse.reshape(bh, 1, sq)
+    delta = delta.reshape(bh, 1, sq)
 
     # ---- dq: (bh, q_blocks, kv_blocks), kv innermost ----
     def kv_im(b, i, j, kv_len_ref):
@@ -340,7 +344,7 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return (b, j, 0)
 
     def q_row_im(b, i, j, ref):
-        return (b, i)
+        return (b, 0, i)
 
     dq_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -352,8 +356,8 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pl.BlockSpec((1, block_k, dv), kv_im, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_q, dv), lambda b, i, j, ref: (b, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q), q_row_im, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q), q_row_im, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, block_q), q_row_im, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, block_q), q_row_im, memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((1, block_q, d),
                                lambda b, i, j, ref: (b, i, 0),
@@ -366,7 +370,7 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                           kv_steps=kv_steps),
         grid_spec=dq_spec,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.float32),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -381,7 +385,7 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     def q_row_im2(b, j, i, kv_len_ref):
         if causal:
             i = jnp.maximum(i, (j * block_k) // block_q)
-        return (b, i)
+        return (b, 0, i)
 
     dkv_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -393,8 +397,10 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pl.BlockSpec((1, block_k, dv), lambda b, j, i, ref: (b, j, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_q, dv), q_im, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q), q_row_im2, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q), q_row_im2, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, block_q), q_row_im2,
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, block_q), q_row_im2,
+                         memory_space=pltpu.VMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, i, ref: (b, j, 0),
@@ -415,7 +421,7 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             jax.ShapeDtypeStruct((bh, skv, d), jnp.float32),
             jax.ShapeDtypeStruct((bh, skv, dv), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

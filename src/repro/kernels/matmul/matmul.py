@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import compiler_params
 
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_scr, *, k_steps: int):
@@ -62,7 +61,7 @@ def matmul(a: jnp.ndarray, b: jnp.ndarray, *, block_m: int = 256,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import compiler_params
 
 
 def _mse_kernel(p_ref, t_ref, o_ref, *, width: int, steps: int):
@@ -69,7 +68,7 @@ def mse_partial_sum(pred: jnp.ndarray, target: jnp.ndarray, *,
         out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

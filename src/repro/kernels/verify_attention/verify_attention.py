@@ -12,9 +12,10 @@ token — the k-for-1 amortization the spec-decode subsystem exists to buy.
 Structure is the paged flash-decode kernel (``kernels/decode_attention``)
 with a widened query block:
 
-  grid = (B, Hkv, logical_blocks), kv innermost with "arbitrary"
-  semantics.  The block table rides the scalar-prefetch channel (SMEM), so
-  each logical block's physical page is resolved before its DMA issues;
+  grid = (B, logical_blocks), kv innermost with "arbitrary" semantics;
+  each K/V block carries every KV head (see ``kernels/decode_attention``).
+  The block table rides the scalar-prefetch channel (SMEM), so each
+  logical block's physical page is resolved before its DMA issues;
   blocks past the window's last position clamp their index — the Pallas
   pipeline only streams a block when its index *changes*, so dead blocks
   cost no fetch.
@@ -27,92 +28,17 @@ with a widened query block:
   written before the kernel runs, exactly like single-token decode).
 
 The online-softmax body (running max / running sum / output accumulator in
-VMEM scratch, row reductions via the ``hw_backend.warp_reduce`` butterfly)
-is shared with the dense decode kernel — T=1 degenerates to it exactly.
+VMEM scratch, row reductions by a lane-rotation tree) is the decode
+kernel's own ``_attend_kernel`` — T=1 degenerates to decode exactly.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import compiler_params
-from repro.kernels.decode_attention.decode_attention import (
-    DEFAULT_MASK_VALUE,
-    _row_reduce,
-)
-
-
-def _verify_kernel(pos_ref, bt_ref, q_ref, *refs,
-                   scale: float, page_size: int, kv_steps: int,
-                   t_window: int, group: int, quantized: bool = False):
-    del bt_ref  # consumed by the index maps, not the body
-    if quantized:
-        k_ref, ks_ref, v_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
-    else:
-        k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
-        ks_ref = vs_ref = None
-    b = pl.program_id(0)
-    kj = pl.program_id(2)
-    pos = pos_ref[b]                       # first window position
-    last = pos + t_window - 1              # most permissive row limit
-
-    @pl.when(kj == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    # skip blocks wholly past the window's last position — verify traffic
-    # tracks the live sequence plus the k-window, not max_seq
-    @pl.when(kj * page_size <= last)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)           # (T*G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)     # (ps, D)
-        if ks_ref is not None:
-            # int8 pages: fused dequant — per-row scales gathered through
-            # the same page index map as the value block
-            k = k * ks_ref[0]                         # (ps, 1)
-        tg = q.shape[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        k_ids = kj * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (tg, page_size), 1)
-        # causal mask within the window: row t*G+g attends positions
-        # <= pos + t (its own K/V row was written before this dispatch)
-        row_limit = pos + jax.lax.broadcasted_iota(
-            jnp.int32, (tg, page_size), 0) // group
-        s = jnp.where(k_ids <= row_limit, s, DEFAULT_MASK_VALUE)
-
-        m_prev = m_scr[...]                           # (T*G, 1)
-        m_cur = _row_reduce(s, page_size, "max")
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                        # (T*G, ps)
-        l_scr[...] = alpha * l_scr[...] + _row_reduce(p, page_size, "sum")
-        v = v_ref[0, :, 0, :].astype(jnp.float32)     # (ps, Dv)
-        if vs_ref is not None:
-            v = v * vs_ref[0]                         # (ps, 1)
-        # zero rows past the window: a fresh growth page reads garbage
-        # (NaN in interpret mode) and 0 * NaN would poison the contraction
-        row_ids = kj * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size, 1), 0)
-        v = jnp.where(row_ids <= last, v, 0.0)
-        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_scr[...] = acc_scr[...] * alpha + pv
-        m_scr[...] = m_new
-
-    @pl.when(kj == kv_steps - 1)
-    def _finalize():
-        l = l_scr[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+from repro.kernels.decode_attention.decode_attention import paged_attend
 
 
 def paged_flash_verify(q: jnp.ndarray, k_pages: jnp.ndarray,
@@ -137,77 +63,9 @@ def paged_flash_verify(q: jnp.ndarray, k_pages: jnp.ndarray,
     page index map and dequant fuses into the gather, exactly as in the
     paged flash-decode kernel.
     """
-    from repro.kernels.common import default_interpret
-
-    if interpret is None:
-        interpret = default_interpret()
-    if (k_scales is None) != (v_scales is None):
-        raise ValueError("pass both k_scales and v_scales or neither")
-    quantized = k_scales is not None
-    b, hkv, tg, d = q.shape
-    if tg % t_window:
-        raise ValueError(f"q rows {tg} not a multiple of t_window={t_window}")
-    group = tg // t_window
-    page_size = k_pages.shape[1]
-    dv = v_pages.shape[-1]
-    nb = block_tables.shape[1]
-    if scale is None:
-        scale = d ** -0.5
-
-    kernel = functools.partial(_verify_kernel, scale=scale,
-                               page_size=page_size, kv_steps=nb,
-                               t_window=t_window, group=group,
-                               quantized=quantized)
-
-    def kv_map(bi, h, j, pos_ref, bt_ref):
-        # clamp at the window's last live block: no fetch past it (dead
-        # slots' runaway pos also clamps to the final table column)
-        jc = jnp.minimum(jnp.minimum(
-            j, (pos_ref[bi] + t_window - 1) // page_size), nb - 1)
-        return (bt_ref[bi, jc], 0, h, 0)
-
-    def scale_map(bi, h, j, pos_ref, bt_ref):
-        jc = jnp.minimum(jnp.minimum(
-            j, (pos_ref[bi] + t_window - 1) // page_size), nb - 1)
-        return (bt_ref[bi, jc], 0, 0)
-
-    q_spec = pl.BlockSpec((1, 1, tg, d),
-                          lambda bi, h, j, pos_ref, bt_ref: (bi, h, 0, 0),
-                          memory_space=pltpu.VMEM)
-    k_spec = pl.BlockSpec((1, page_size, 1, d), kv_map,
-                          memory_space=pltpu.VMEM)
-    v_spec = pl.BlockSpec((1, page_size, 1, dv), kv_map,
-                          memory_space=pltpu.VMEM)
-    s_spec = pl.BlockSpec((1, page_size, 1), scale_map,
-                          memory_space=pltpu.VMEM)
-    if quantized:
-        in_specs = [q_spec, k_spec, s_spec, v_spec, s_spec]
-        operands = (q, k_pages, k_scales[..., None], v_pages,
-                    v_scales[..., None])
-    else:
-        in_specs = [q_spec, k_spec, v_spec]
-        operands = (q, k_pages, v_pages)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv, nb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, tg, dv),
-                               lambda bi, h, j, pos_ref, bt_ref:
-                               (bi, h, 0, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((tg, 1), jnp.float32),
-            pltpu.VMEM((tg, 1), jnp.float32),
-            pltpu.VMEM((tg, dv), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, tg, dv), q.dtype),
-        compiler_params=compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(pos.astype(jnp.int32), block_tables.astype(jnp.int32), *operands)
+    if q.shape[2] % t_window:
+        raise ValueError(f"q rows {q.shape[2]} not a multiple of "
+                         f"t_window={t_window}")
+    return paged_attend(q, k_pages, v_pages, block_tables, pos,
+                        t_window=t_window, scale=scale, k_scales=k_scales,
+                        v_scales=v_scales, interpret=interpret)

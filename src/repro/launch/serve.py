@@ -1,5 +1,12 @@
 """Serving launcher: continuous-batching engine with either cache layout.
 
+By default the model is built at its published widths with bf16 params
+and compute, as a deployment holds it — that is the chip path
+(``chip_smoke.py`` drives the same engine).  ``--reduced`` selects the
+laptop-scale config (d_model 256, vocab 512, float32 compute) that the
+CPU examples below and the tests use; off the TPU the Pallas kernels run
+in interpret mode and ``auto`` lowerings pick ``jnp``.
+
 Runs the fused zero-copy decode fast path by default; ``--no-fused``
 selects the seed per-token-dispatch loop for comparison, and
 ``--cache-layout paged`` swaps the dense slot pool for the paged block
@@ -23,7 +30,7 @@ restores them with no recompute instead of requeue-and-recompute, and
 ``--evict-policy`` / ``--min-cached-tokens`` tune the prefix index's
 eviction order and admission threshold.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \\
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --reduced \\
       --cache-layout paged --kv-dtype int8 --num-pages 12 --preempt swap
 
 Fault tolerance: ``--deadline-ms`` / ``--ttft-deadline-ms`` attach
@@ -36,15 +43,15 @@ random fault schedule (OOM, NaN, kernel failure, stragglers, spec
 collapse, cancels, page corruption) against the batch — the status
 column then shows each request's terminal state.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --reduced \
       --requests 6 --prompt-len 16 --max-new 12
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --reduced \
       --cache-layout paged --page-size 16 --num-pages 24
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --reduced \
       --cache-layout paged --spec-k 4 --draft self:2
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --reduced \
       --cache-layout paged --prefix-sharing --shared-prefix 32
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --reduced \
       --cache-layout paged --inject-faults 0 --audit --deadline-ms 5000
 
 Open-loop traffic: ``--workload poisson|bursty`` replays a deterministic
@@ -58,7 +65,7 @@ and ``--prefill-budget`` caps prompt tokens prefilled per round
 (chunked prefill).  Every run ends with the SLA block — TTFT/TBT
 p50/p95/p99, goodput, and the terminal-status census.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --reduced \
       --cache-layout paged --workload poisson --arrival-rate 16 \
       --requests 12 --queue-watermark 4 --shed-priority 2
 
@@ -72,10 +79,10 @@ for any topology; the run ends with the fleet SLA, per-replica census,
 and router decision counts.  With ``--inject-faults`` each worker runs
 its own deterministically derived fault schedule.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --reduced \
       --cache-layout paged --replicas 3 --router cache-aware \
       --prefix-sharing --shared-prefix 32
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --reduced \
       --cache-layout paged --replicas 3 --disaggregate
 """
 
@@ -89,7 +96,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.registry import reduced_config
+from repro.configs.registry import get_config, reduced_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.lm import Model
 from repro.serve.async_engine import serve_open_loop
 from repro.serve.cluster import ROUTER_POLICIES, make_cluster
@@ -159,6 +167,10 @@ def _serve_cluster(args, model, params, cfg, engine_kw, open_loop,
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="laptop-scale config with float32 compute (CPU "
+                         "runs and tests); default: published widths, "
+                         "bf16")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
@@ -327,8 +339,12 @@ def main():
         ap.error("--disaggregate needs --replicas >= 2 (at least one "
                  "prefill and one decode worker)")
 
-    cfg = reduced_config(args.arch)
-    model = Model(cfg, compute_dtype=jnp.float32,
+    enable_compile_cache()
+    if args.reduced:
+        cfg, dtype = reduced_config(args.arch), jnp.float32
+    else:
+        cfg, dtype = get_config(args.arch), jnp.bfloat16
+    model = Model(cfg, param_dtype=dtype, compute_dtype=dtype,
                   attn_backend=None if args.attn_backend == "auto"
                   else args.attn_backend)
     params = model.init(jax.random.PRNGKey(args.seed))

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.configs.registry import get_config, reduced_config
 from repro.data.pipeline import DataConfig, SyntheticPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.layers import WarpFeatureConfig
 from repro.models.lm import Model
 from repro.optim.optimizer import AdamWConfig
@@ -45,6 +46,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     wf = WarpFeatureConfig(
         reduction_backend=None if args.warp_backend == "auto"

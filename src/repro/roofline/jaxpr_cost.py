@@ -27,7 +27,6 @@ per-chip roofline terms.
 
 from __future__ import annotations
 
-import functools
 from typing import Dict, Tuple
 
 import jax
@@ -89,7 +88,7 @@ _POINTER_UPDATE = {"scatter", "scatter-add", "scatter_add",
                    "dynamic_update_slice"}
 
 # cap on grid points we are willing to walk when replaying Pallas block
-# index maps; beyond it fall back to coarse operand+result accounting
+# index maps; a larger grid is an error, not a coarser number
 _PALLAS_MAX_STEPS = 1 << 16
 # stand-in for scalar-prefetch operands (valid lengths, positions, block
 # tables) when replaying index maps at trace time.  Values are
@@ -121,7 +120,8 @@ def _pallas_block_traffic(eqn) -> float:
     grid = tuple(int(g) for g in gm.grid)
     steps = int(np.prod(grid)) if grid else 1
     if steps > _PALLAS_MAX_STEPS:
-        raise ValueError("grid too large to replay")
+        raise ValueError(f"pallas grid {grid} has {steps} steps, more than "
+                         f"the replay bound {_PALLAS_MAX_STEPS}")
     n_idx = int(getattr(gm, "num_index_operands", 0))
     scalar_args = []
     for v in eqn.invars[:n_idx]:
@@ -138,11 +138,10 @@ def _pallas_block_traffic(eqn) -> float:
         points = [p + (i,) for p in points for i in range(g)]
     total = 0.0
     for bm in gm.block_mappings:
-        shape_dtype = bm.array_shape_dtype
-        block_shape = tuple(int(s) if isinstance(s, (int, np.integer)) else 1
-                            for s in bm.block_shape)
-        block_bytes = float(np.prod(block_shape)
-                            * np.dtype(shape_dtype.dtype).itemsize)
+        # ref_aval is the block as the kernel sees it (squeezed dims
+        # dropped): same element count as the transferred block
+        block_bytes = float(np.prod(bm.ref_aval.shape)
+                            * np.dtype(bm.array_aval.dtype).itemsize)
         im = bm.index_map_jaxpr
         run = _index_map_runner(im)
         prev = None
@@ -167,18 +166,15 @@ def _index_map_runner(im):
     this is what lets the replay follow ``pos``-clamped *and*
     block-table-gathered index maps instead of falling back to coarse
     operand accounting."""
+    from jax._src.state.discharge import discharge_state
+
     n_out = len(im.jaxpr.outvars)
-    try:
-        from jax._src.state.discharge import discharge_state
+    d_jaxpr, d_consts = discharge_state(im.jaxpr, im.consts)
 
-        d_jaxpr, d_consts = discharge_state(im.jaxpr, im.consts)
+    def run(*args):
+        return jax.core.eval_jaxpr(d_jaxpr, d_consts, *args)[:n_out]
 
-        def run(*args):
-            return jax.core.eval_jaxpr(d_jaxpr, d_consts, *args)[:n_out]
-
-        return run
-    except ImportError:
-        return functools.partial(jax.core.eval_jaxpr, im.jaxpr, im.consts)
+    return run
 
 
 def _pallas_cost(eqn) -> Tuple[float, float]:
@@ -192,18 +188,9 @@ def _pallas_cost(eqn) -> Tuple[float, float]:
     HW-path property the proxy exists to measure.
     """
     body_f, _ = jaxpr_cost(eqn.params["jaxpr"])
-    try:
-        grid = tuple(int(g) for g in eqn.params["grid_mapping"].grid)
-        steps = float(np.prod(grid)) if grid else 1.0
-    except Exception:
-        steps = 1.0
-    try:
-        mem = _pallas_block_traffic(eqn)
-    except Exception:
-        mem = sum(_aval_bytes(v.aval) for v in eqn.invars
-                  if hasattr(v, "aval"))
-        mem += sum(_aval_bytes(v.aval) for v in eqn.outvars)
-    return steps * body_f, mem
+    grid = tuple(int(g) for g in eqn.params["grid_mapping"].grid)
+    steps = float(np.prod(grid)) if grid else 1.0
+    return steps * body_f, _pallas_block_traffic(eqn)
 
 
 def jaxpr_cost(jaxpr) -> Tuple[float, float]:

@@ -47,6 +47,8 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
+import jax
+
 from repro.serve import sla
 from repro.serve.async_engine import TokenStream
 from repro.serve.audit import AuditReport, audit_fleet
@@ -472,9 +474,14 @@ def make_cluster(model, params, *, replicas: int = 2,
                  catalog_refresh: int = 8,
                  **engine_kw) -> ClusterController:
     """Build a fleet: ``replicas`` workers over identically-configured
-    paged engines (one shared engine object by default — sessions are
-    independent, and sharing reuses the jit caches instead of compiling
-    per replica), a router with the given policy, and a controller.
+    paged engines, a router with the given policy, and a controller.
+
+    Worker i runs on ``jax.devices()[i % n]`` — one replica per chip on a
+    multi-chip host — with its own copy of ``params`` committed there.
+    Workers on the same device share one engine object by default —
+    sessions are independent, and sharing reuses the jit caches instead
+    of compiling per replica; ``share_engine=False`` gives every worker
+    its own.
 
     ``disaggregate=True`` splits roles: the first ``prefill_workers``
     replicas only prefill (their sessions never decode) and the rest
@@ -491,12 +498,17 @@ def make_cluster(model, params, *, replicas: int = 2,
         raise ValueError(f"prefill_workers must be in [1, {replicas - 1}]; "
                          f"got {prefill_workers}")
     engine_kw.setdefault("cache_layout", "paged")
-    engines = [ServeEngine(model, params, **engine_kw)]
-    if not share_engine:
-        engines += [ServeEngine(model, params, **engine_kw)
-                    for _ in range(replicas - 1)]
+    devices = jax.devices()
+    placed: Dict[Any, Any] = {}       # device -> params committed there
+    engines: Dict[Any, ServeEngine] = {}
     workers = []
     for i in range(replicas):
+        device = devices[i % len(devices)]
+        if device not in placed:
+            placed[device] = jax.device_put(params, device)
+        key = device if share_engine else i
+        if key not in engines:
+            engines[key] = ServeEngine(model, placed[device], **engine_kw)
         if disaggregate:
             role = "prefill" if i < prefill_workers else "decode"
         else:
@@ -506,10 +518,9 @@ def make_cluster(model, params, *, replicas: int = 2,
             faults = worker_faults.get(i)
         elif faults_seed is not None:
             faults = FaultSchedule.random_for_worker(faults_seed, i)
-        workers.append(EngineWorker(
-            i, engines[0] if share_engine else engines[i],
-            role=role, faults=faults))
+        workers.append(EngineWorker(i, engines[key], role=role,
+                                    faults=faults))
     router = Router([w.worker_id for w in workers], policy=router_policy,
-                    page_size=engines[0].page_size)
+                    page_size=workers[0].engine.page_size)
     return ClusterController(workers, router,
                              catalog_refresh=catalog_refresh)

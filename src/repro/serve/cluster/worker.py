@@ -27,7 +27,10 @@ implementation instead of two:
 
 Several workers may share one ``ServeEngine`` *object* (sessions carry
 all mutable state, so this is safe) — that is how a fleet of smoke-test
-replicas reuses one set of jit caches instead of compiling per replica.
+replicas on one device reuses one set of jit caches instead of compiling
+per replica.  A worker runs on its engine's device: an engine whose
+params are committed to a chip builds its session state there, so
+``make_cluster`` on a four-chip host puts one replica on each chip.
 """
 
 from __future__ import annotations
@@ -139,10 +142,11 @@ class EngineWorker:
             # router catalog; it commits at the top of our next step.
             # (_migrate_out commits first, so prefill handoffs — and any
             # rebalancing detach — always snapshot settled pages.)
-            if self.engine.pipeline:
-                self.engine.dispatch_round(self._st)
-            else:
-                self.engine._round(self._st)
+            with self.engine.on_device():
+                if self.engine.pipeline:
+                    self.engine.dispatch_round(self._st)
+                else:
+                    self.engine._round(self._st)
         except BaseException as exc:
             self.fail(exc)
             raise

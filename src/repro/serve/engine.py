@@ -89,17 +89,19 @@ terminal status in ``last_stats[uid]["status"]``:
               retry-with-requeue budget ran out across step-restart
               recoveries
 
-Recovery is step-restart: a recoverable mid-step exception (allocator
-OOM, kernel-backend failure) releases every live slot, requeues each
-request with its generated tokens folded into its prompt (charging one
-retry), and rebuilds the manager + device pool from scratch — the
-``(uid, position)`` sampling keys make the replay bit-identical, the
-same property preemption rides on.  A kernel-backend failure
-additionally degrades the engine onto the chunked-``jnp`` SW path
-(``backend_degraded``) — the paper's HW-vs-SW interchangeability as a
-runtime policy.  Speculative decoding auto-disables per request when its
-acceptance collapses (window of 1-token commits) and re-enables after a
-cooldown.  ``repro.serve.faults`` injects all of these
+Recovery is step-restart: an injected, non-fatal mid-step fault
+(allocator OOM, kernel-backend failure) releases every live slot,
+requeues each request with its generated tokens folded into its prompt
+(charging one retry), and rebuilds the manager + device pool from
+scratch — the ``(uid, position)`` sampling keys make the replay
+bit-identical, the same property preemption rides on.  Any other
+exception escapes the session (``_abort`` releases everything on the
+way out).  An injected kernel-backend failure additionally degrades the
+engine onto the chunked-``jnp`` SW path (``backend_degraded``) — the
+paper's HW-vs-SW interchangeability as a runtime policy.  Speculative
+decoding auto-disables per request when its acceptance collapses
+(window of 1-token commits) and re-enables after a cooldown.
+``repro.serve.faults`` injects all of these
 deterministically; ``repro.serve.audit`` sweeps the allocator / block
 table / prefix index invariants per round under ``audit=True`` and
 always after ``serve()`` (via ``last_pool_stats``).
@@ -110,6 +112,7 @@ the benchmark baseline (``benchmarks/serve_decode.py``).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import statistics
 import time
@@ -121,7 +124,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.serve import calibrate, sla, spec_decode
-from repro.serve.audit import AuditError, audit_pool
+from repro.serve.audit import audit_pool
 from repro.serve.faults import InjectedFault, KernelBackendError, poison_pages
 from repro.serve.kv_cache import (
     CACHE_LAYOUTS,
@@ -237,6 +240,13 @@ class PendingRound:
 _PADDED_PREFILL_FAMILIES = ("dense",)
 
 
+def _home_device(params):
+    """The single device every parameter is committed to, else None."""
+    devices = {d for x in jax.tree.leaves(params)
+               if getattr(x, "committed", False) for d in x.devices()}
+    return devices.pop() if len(devices) == 1 else None
+
+
 class ServeEngine:
     def __init__(self, model, params, *, max_seq: int, batch_slots: int,
                  temperature: float = 0.0, seed: int = 0,
@@ -311,6 +321,9 @@ class ServeEngine:
                              f"{prefill_budget}")
         self.model = model
         self.params = params
+        # params committed to one device (a cluster worker's chip) pin the
+        # engine there: pools, slot state and draft cache are built on it
+        self.device = _home_device(params)
         self.max_seq = max_seq
         self.slots = batch_slots
         self.temperature = temperature
@@ -662,18 +675,20 @@ class ServeEngine:
         """
         st = self._open_session(requests, faults)
         try:
-            if self.pipeline:
-                # overlapped rounds: each iteration commits the previous
-                # round's in-flight step after the queue-side sweeps, so
-                # host scheduling runs while the device computes.  A live
-                # slot pins the loop until its step commits, so the loop
-                # always exits with nothing pending.
-                while st.queue or st.live or st.prefilling \
-                        or st.pending is not None:
-                    self.dispatch_round(st)
-            else:
-                while st.queue or st.live or st.prefilling:
-                    self._round(st)
+            with self.on_device():
+                if self.pipeline:
+                    # overlapped rounds: each iteration commits the
+                    # previous round's in-flight step after the queue-side
+                    # sweeps, so host scheduling runs while the device
+                    # computes.  A live slot pins the loop until its step
+                    # commits, so the loop always exits with nothing
+                    # pending.
+                    while st.queue or st.live or st.prefilling \
+                            or st.pending is not None:
+                        self.dispatch_round(st)
+                else:
+                    while st.queue or st.live or st.prefilling:
+                        self._round(st)
         except BaseException as exc:
             # exception safety: whatever escapes, no slot or page stays
             # held and every in-flight request gets a terminal status —
@@ -961,11 +976,13 @@ class ServeEngine:
             st.pending_swaps.clear()
 
     def _recover_or_raise(self, st: "_SchedState", exc: Exception):
-        """Shared recovery gate for both round drivers: audit failures,
-        fatal injected faults, and exhausted recovery budgets escape;
-        everything else takes step-restart recovery."""
-        if (isinstance(exc, AuditError)
-                or (isinstance(exc, InjectedFault) and exc.fatal)
+        """Shared recovery gate for both round drivers.  Only a
+        non-fatal injected fault within the recovery budget takes
+        step-restart recovery; everything else escapes — a real error
+        from a dispatch (a kernel the compiler refused, a device fault,
+        an audit failure) must surface, not be replayed on the same
+        device or hidden behind a backend switch."""
+        if (not isinstance(exc, InjectedFault) or exc.fatal
                 or st.recoveries >= self.max_recoveries):
             raise exc
         self._recover(st, exc)
@@ -1098,32 +1115,40 @@ class ServeEngine:
 
             st.mgr.allocator.fault_hook = oom_hook
 
+    def on_device(self):
+        """Context in which new arrays land on this engine's device
+        (a no-op for an engine whose params are not committed to one)."""
+        if self.device is None:
+            return contextlib.nullcontext()
+        return jax.default_device(self.device)
+
     def _init_device(self, st: "_SchedState"):
         """Fresh device-side pool + slot state (used at serve() start and
         again by step-restart recovery)."""
-        if st.mgr is not None:
-            st.pool = self.model.init_cache(
-                self.slots, self.max_seq, layout="paged",
-                page_size=self.page_size, num_pages=self.num_pages,
-                kv_dtype=self.kv_dtype)
-            st.pool.pop("block_tables")  # the manager owns the mapping
-            st.bt_dev = st.mgr.device_tables()
-            st.cache = None
-        else:
-            st.cache = self.model.init_cache(self.slots, self.max_seq)
-        st.pos = jnp.zeros((self.slots,), jnp.int32)
-        st.tok = jnp.zeros((self.slots,), jnp.int32)
-        st.remaining = jnp.zeros((self.slots,), jnp.int32)
-        st.uids = jnp.zeros((self.slots,), jnp.int32)
-        st.zero_mask = jnp.zeros((self.slots,), jnp.bool_)
-        st.slot_pos = [0] * self.slots        # host mirror (no device sync)
-        st.plans.clear()
-        st.prefilling.clear()
-        st.gate_block = None
-        if self.spec_k > 1:
-            st.draft_cache = self.draft_model.init_cache(self.slots,
-                                                         self.max_seq)
-            st.spec_mask = jnp.zeros((self.slots,), jnp.bool_)
+        with self.on_device():
+            if st.mgr is not None:
+                st.pool = self.model.init_cache(
+                    self.slots, self.max_seq, layout="paged",
+                    page_size=self.page_size, num_pages=self.num_pages,
+                    kv_dtype=self.kv_dtype)
+                st.pool.pop("block_tables")  # the manager owns the mapping
+                st.bt_dev = st.mgr.device_tables()
+                st.cache = None
+            else:
+                st.cache = self.model.init_cache(self.slots, self.max_seq)
+            st.pos = jnp.zeros((self.slots,), jnp.int32)
+            st.tok = jnp.zeros((self.slots,), jnp.int32)
+            st.remaining = jnp.zeros((self.slots,), jnp.int32)
+            st.uids = jnp.zeros((self.slots,), jnp.int32)
+            st.zero_mask = jnp.zeros((self.slots,), jnp.bool_)
+            st.slot_pos = [0] * self.slots    # host mirror (no device sync)
+            st.plans.clear()
+            st.prefilling.clear()
+            st.gate_block = None
+            if self.spec_k > 1:
+                st.draft_cache = self.draft_model.init_cache(self.slots,
+                                                             self.max_seq)
+                st.spec_mask = jnp.zeros((self.slots,), jnp.bool_)
 
     # ------------------------------------------------------- fault plumbing
     def _apply_round_faults(self, st: "_SchedState", poison: bool = True):
@@ -1254,16 +1279,13 @@ class ServeEngine:
         deliberate: after an arbitrary mid-step exception the pool, the
         donated device buffers, and the prefix index cannot be trusted to
         agree, and a stale index pointing into a reinitialized pool would
-        serve zeroed K/V as if it were cached prefix.  Kernel-backend
-        failures additionally degrade the engine onto the chunked-jnp SW
-        path before the replay."""
+        serve zeroed K/V as if it were cached prefix.  An injected
+        kernel-backend failure additionally degrades the engine onto the
+        chunked-jnp SW path before the replay; other injected faults (hard
+        OOM) restart on the same backends."""
         st.recoveries += 1
         self.recoveries += 1
-        if isinstance(exc, KernelBackendError) or not isinstance(
-                exc, InjectedFault):
-            # injected non-kernel faults (hard OOM) restart on the same
-            # backends; anything surfacing from a real dispatch — or the
-            # explicit kernel fault — falls back to the SW path
+        if isinstance(exc, KernelBackendError):
             self._degrade_to_sw()
         now = time.perf_counter() - st.t0
         held = {**st.live, **{s: cs.req for s, cs in st.prefilling.items()}}

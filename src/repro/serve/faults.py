@@ -86,7 +86,7 @@ class InjectedFault(RuntimeError):
 
 
 class KernelBackendError(InjectedFault):
-    """A kernel-backend dispatch failure (injected or wrapped-real).
+    """An injected kernel-backend dispatch failure.
 
     The engine reacts by rebuilding its step functions on the chunked-jnp
     SW path and replaying the interrupted step — requests never observe

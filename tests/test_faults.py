@@ -379,6 +379,35 @@ def test_kernel_fault_degrades_to_sw():
     _assert_clean(eng)
 
 
+@pytest.mark.parametrize("pipeline", [True, False])
+def test_real_step_exception_escapes_without_degrading(pipeline):
+    """A plain exception from a real dispatch (a refused kernel, a device
+    fault) is not the injected kernel fault: it escapes the session with
+    no step restart and no switch to the jnp lowering."""
+    reqs = _reqs(4, mlo=6, mhi=10)
+    eng = _engine(pipeline=pipeline)
+    base = eng.serve(copy.deepcopy(reqs))
+    real_step = eng._paged_step
+    calls = []
+
+    def failing_step(*args):
+        calls.append(None)
+        if len(calls) == 3:
+            raise RuntimeError("device fault")
+        return real_step(*args)
+
+    eng._paged_step = failing_step
+    with pytest.raises(RuntimeError, match="device fault"):
+        eng.serve(copy.deepcopy(reqs))
+    assert not eng.backend_degraded
+    assert eng.recoveries == 0
+    assert eng.model.decode_backend is None     # auto lowering kept
+    assert STATUS_FAILED in _statuses(eng).values()
+    _assert_clean(eng)
+    eng._paged_step = real_step
+    assert eng.serve(copy.deepcopy(reqs)) == base
+
+
 def test_soft_oom_blocks_then_drains():
     """A soft-OOM window denies admission/growth without raising; the
     engine preempts or waits it out and finishes bit-identically."""
