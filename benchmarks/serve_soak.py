@@ -196,6 +196,7 @@ def run_overlap(model, params, cfg, smoke: bool = False,
             "rounds": rounds, "wall_s": wall,
             "rounds_per_s": rounds / max(wall, 1e-9),
             "dispatch_s_mean": phases.get("dispatch_s_mean"),
+            "fetch_s_mean": phases.get("fetch_s_mean"),
             "commit_s_mean": phases.get("commit_s_mean"),
             "overlap_s_mean": phases.get("overlap_s_mean"),
             "statuses": stats["sla"]["statuses"],

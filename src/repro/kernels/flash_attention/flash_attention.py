@@ -44,6 +44,8 @@ DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 # lse stand-in for fully-masked rows: large positive so exp(s - lse)
 # underflows to exactly 0 in the backward rebuild
 FULLY_MASKED_LSE = 0.7 * float(jnp.finfo(jnp.float32).max)
+# the forward kernel's op name on the chip (HLO instruction and trace)
+FWD_KERNEL_NAME = "flash_attention_fwd_kernel"
 
 
 def _last_kv_block(kv_len, block_k: int, kv_steps: int):
@@ -194,6 +196,8 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        # the op's name on the chip's trace, inside a layer scan too
+        name=FWD_KERNEL_NAME,
     )(kv_len.astype(jnp.int32), q, k, v)
     return o, lse[:, 0]
 

@@ -47,6 +47,7 @@ from collections import deque
 from typing import Dict, List, Optional
 
 from repro.serve.engine import STATUS_OK, Request, ServeEngine
+from repro.serve.trace import span
 from repro.serve.workload import TimedRequest
 
 _DONE = object()
@@ -266,7 +267,8 @@ class AsyncServeEngine:
         eng, st = self.engine, self._st
         try:
             while True:
-                self._ingest(st)
+                with span(st, "serve.ingest"):
+                    self._ingest(st)
                 work = bool(st.queue or st.live or st.prefilling
                             or st.pending is not None)
                 arrivals = bool(self._scheduled or self._pending)
@@ -291,7 +293,8 @@ class AsyncServeEngine:
                     eng.dispatch_round(st)
                 else:
                     eng._round(st)
-                self._publish(st)
+                with span(st, "serve.publish"):
+                    self._publish(st)
                 self._round_evt.set()   # re-check blocked submitters
                 await asyncio.sleep(0)
             self._results = eng._finalize_session(st)

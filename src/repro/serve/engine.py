@@ -141,6 +141,7 @@ from repro.serve.kv_cache import (
     write_slots,
 )
 from repro.serve.prefix_index import EVICT_POLICIES, PrefixIndex
+from repro.serve.trace import SPANS, column, new_phases, span
 
 # terminal request statuses (last_stats[uid]["status"]) — every request
 # handed to serve() ends in exactly one of these
@@ -151,6 +152,18 @@ STATUS_CANCELLED = "cancelled"
 STATUS_FAILED = "failed"
 TERMINAL_STATUSES = (STATUS_OK, STATUS_SHED, STATUS_TIMEOUT,
                      STATUS_CANCELLED, STATUS_FAILED)
+
+# work counters, counted where the engine chooses the shapes; exported as
+# last_stats["counters"], mid-session as ServeEngine.counters(st), and per
+# round (cumulative) in the timeseries
+COUNTERS = (
+    "decode_steps",
+    "decode_ctx_tokens",    # sum over steps of live rows' slot_pos + 1
+    "decode_grid_tokens",   # sum over steps of slots x attention bucket
+    "prefill_tokens",       # uncached prompt tokens computed
+    "prefill_causal_keys",  # sum of n * start + n (n + 1) / 2
+    "admissions",           # requests whose first token a prefill sampled
+)
 
 # bounded-queue shed policies: who gets rejected when the waiting queue
 # overflows max_queue
@@ -228,7 +241,6 @@ class PendingRound:
     live: Dict[int, Request]
     spec: bool = False
     t_start: float = 0.0        # watchdog clock start (at dispatch)
-    dispatch_s: float = 0.0     # host time spent issuing the dispatch
     live_before: int = 0
 
 
@@ -803,7 +815,10 @@ class ServeEngine:
         self.commit_round(st)
         slot = next(s for s, r in st.live.items() if r.uid == uid)
         req = st.live.pop(slot)
-        handle = st.mgr.swap_out(slot, st.pool, st.slot_pos[slot])
+        handle = st.mgr.swap_out(slot, st.pool, st.slot_pos[slot],
+                                 async_copy=True)
+        st.pending_swaps.append(handle)
+        self._drain_swaps(st)
         resume = dataclasses.replace(
             req, prompt=list(req.prompt) + req.generated[req.folded:],
             folded=len(req.generated))
@@ -855,33 +870,31 @@ class ServeEngine:
         nothing to do (the round/fault clock still ticks — the async
         driver relies on that to reach scheduled arrivals)."""
         st.rnd += 1
-        st.last_dispatch_s = st.last_commit_s = st.last_overlap_s = 0.0
-        self._apply_round_faults(st)
-        self._expire_and_cancel(st)
-        self._admission_control(st)
-        if st.queue or st.live or st.prefilling:
-            try:
-                if self.prefix_sharing:
-                    self._admit_shared(st)
-                else:
-                    self._admit(st)
-                # a prefill-role cluster worker stops at admission: its
-                # live slots (prompt prefilled, first token sampled) are
-                # migrated out by the worker right after the round, so
-                # growth and decode would be wasted work
-                if st.live and not st.prefill_only:
-                    if st.mgr is not None:
-                        self._grow_or_preempt(st)
-                    if st.live:
-                        self._timed_step(st)
-            except Exception as exc:
-                self._recover_or_raise(st, exc)
-            if self.audit and st.mgr is not None:
-                st.mgr.audit().raise_if_failed()
-                if st.pool is not None:
-                    # structural only: injected page corruption must
-                    # surface as NaN logits, not as an audit failure
-                    audit_pool(st.mgr, st.pool).raise_if_failed()
+        with span(st, "serve.round"):
+            with span(st, "serve.sweep"):
+                self._apply_round_faults(st)
+                self._expire_and_cancel(st)
+                self._admission_control(st)
+            if st.queue or st.live or st.prefilling:
+                try:
+                    self._admit_queued(st)
+                    # a prefill-role cluster worker stops at admission:
+                    # its live slots (prompt prefilled, first token
+                    # sampled) are migrated out by the worker right after
+                    # the round, so growth and decode would be wasted work
+                    if st.live and not st.prefill_only:
+                        if st.mgr is not None:
+                            self._grow_or_preempt(st)
+                        if st.live:
+                            self._timed_step(st)
+                except Exception as exc:
+                    self._recover_or_raise(st, exc)
+                if self.audit and st.mgr is not None:
+                    st.mgr.audit().raise_if_failed()
+                    if st.pool is not None:
+                        # structural only: injected page corruption must
+                        # surface as NaN logits, not as an audit failure
+                        audit_pool(st.mgr, st.pool).raise_if_failed()
         self._sample_timeseries(st)
 
     def dispatch_round(self, st: "_SchedState"):
@@ -912,45 +925,49 @@ class ServeEngine:
         difference is that a session runs one extra (otherwise empty)
         trailing round to commit the last step."""
         st.rnd += 1
-        st.last_dispatch_s = st.last_commit_s = st.last_overlap_s = 0.0
-        t_gap = time.perf_counter()
-        self._apply_round_faults(st, poison=False)
-        self._expire_and_cancel(st, scope="queued")
-        self._admission_control(st)
-        if st.pending is not None:
-            st.last_overlap_s = time.perf_counter() - t_gap
-        try:
-            self.commit_round(st)
-        except Exception as exc:
-            self._recover_or_raise(st, exc)
-        self._apply_poison_faults(st)
-        self._expire_and_cancel(st, scope="held")
-        if st.queue or st.live or st.prefilling:
+        with span(st, "serve.round"):
+            st.overlapped = st.pending is not None
+            with span(st, "serve.sweep"):
+                self._apply_round_faults(st, poison=False)
+                self._expire_and_cancel(st, scope="queued")
+                self._admission_control(st)
             try:
-                if self.prefix_sharing:
-                    self._admit_shared(st)
-                else:
-                    self._admit(st)
-                if st.live and not st.prefill_only:
-                    if st.mgr is not None:
-                        self._grow_or_preempt(st)
-                    if st.live:
-                        st.pending = self._timed_dispatch(st)
+                self.commit_round(st)
             except Exception as exc:
                 self._recover_or_raise(st, exc)
-            if self.audit and st.mgr is not None:
-                # audit is a debug mode: force the in-flight step to
-                # commit so the auditor sees a quiescent pool (spec
-                # retraction applied, donated buffers settled) — costs
-                # this round's overlap, keeps per-round coverage
+            self._apply_poison_faults(st)
+            self._expire_and_cancel(st, scope="held")
+            if st.queue or st.live or st.prefilling:
                 try:
-                    self.commit_round(st)
+                    self._admit_queued(st)
+                    if st.live and not st.prefill_only:
+                        if st.mgr is not None:
+                            self._grow_or_preempt(st)
+                        if st.live:
+                            st.pending = self._timed_dispatch(st)
                 except Exception as exc:
                     self._recover_or_raise(st, exc)
-                st.mgr.audit().raise_if_failed()
-                if st.pool is not None:
-                    audit_pool(st.mgr, st.pool).raise_if_failed()
+                if self.audit and st.mgr is not None:
+                    # audit is a debug mode: force the in-flight step to
+                    # commit so the auditor sees a quiescent pool (spec
+                    # retraction applied, donated buffers settled) —
+                    # costs this round's overlap, keeps per-round coverage
+                    try:
+                        self.commit_round(st)
+                    except Exception as exc:
+                        self._recover_or_raise(st, exc)
+                    st.mgr.audit().raise_if_failed()
+                    if st.pool is not None:
+                        audit_pool(st.mgr, st.pool).raise_if_failed()
         self._sample_timeseries(st)
+
+    def _admit_queued(self, st: "_SchedState"):
+        """Admission phase of a round, with either admission path."""
+        with span(st, "serve.admit"):
+            if self.prefix_sharing:
+                self._admit_shared(st)
+            else:
+                self._admit(st)
 
     def commit_round(self, st: "_SchedState"):
         """Commit the in-flight step, if any.  The pending round is
@@ -971,8 +988,9 @@ class ServeEngine:
         boundary and before anything that hands a handle across
         sessions."""
         if st.pending_swaps:
-            for handle in st.pending_swaps:
-                handle.materialize()
+            with span(st, "serve.swap_fetch"):
+                for handle in st.pending_swaps:
+                    handle.materialize()
             st.pending_swaps.clear()
 
     def _recover_or_raise(self, st: "_SchedState", exc: Exception):
@@ -1013,8 +1031,17 @@ class ServeEngine:
             wall_s=time.perf_counter() - st.t0,
             timeseries=st.timeseries)
         st.stats["timeseries"] = st.timeseries
+        st.stats["counters"] = self.counters(st)
+
+    def counters(self, st: "_SchedState") -> Dict[str, int]:
+        """The session's work counters so far (see :data:`COUNTERS`)."""
+        return dict(st.counters)
 
     def _sample_timeseries(self, st: "_SchedState"):
+        """One row per round: queue and slot state, the round's phase
+        sums from the span helper (host seconds, one ``<phase>_s``
+        column per span, then zeroed so that work between rounds counts
+        in the next row), and the cumulative counters."""
         ts = st.timeseries
         ts["t_s"].append(time.perf_counter() - st.t0)
         ts["round"].append(st.rnd)
@@ -1022,9 +1049,14 @@ class ServeEngine:
         busy = len(st.live) + len(st.prefilling)
         ts["live_slots"].append(busy)
         ts["utilization"].append(busy / max(1, self.slots))
-        ts["dispatch_s"].append(st.last_dispatch_s)
-        ts["commit_s"].append(st.last_commit_s)
-        ts["overlap_s"].append(st.last_overlap_s)
+        for name, secs in st.phase_s.items():
+            ts[column(name)].append(secs)
+        ts["overlap_s"].append(st.phase_s["serve.sweep"] if st.overlapped
+                               else 0.0)
+        for name, n in st.counters.items():
+            ts[name].append(n)
+        st.phase_s = new_phases()
+        st.overlapped = False
         if st.mgr is not None:
             ts["free_pages"].append(st.mgr.allocator.free)
 
@@ -1380,15 +1412,14 @@ class ServeEngine:
                     f"injected kernel-backend failure at round {st.rnd}",
                     fatal=f.fatal)
             sleep = fs.straggler_sleep(st.rnd)
-        t_start = time.perf_counter()
-        if sleep:
-            time.sleep(sleep)
-        pending = (self._dispatch_spec(st) if self.spec_k > 1
-                   else self._dispatch_step(st))
+        with span(st, "serve.dispatch"):
+            t_start = time.perf_counter()
+            if sleep:
+                time.sleep(sleep)
+            pending = (self._dispatch_spec(st) if self.spec_k > 1
+                       else self._dispatch_step(st))
         pending.t_start = t_start
-        pending.dispatch_s = time.perf_counter() - t_start
         pending.live_before = len(pending.live)
-        st.last_dispatch_s = pending.dispatch_s
         return pending
 
     def _timed_commit(self, st: "_SchedState", pending: PendingRound):
@@ -1396,11 +1427,8 @@ class ServeEngine:
         dispatch-to-commit wall time blows past ``straggler_factor`` x
         the recent median is recorded in ``last_stats['stragglers']``
         (the trainer's watchdog ported to the serve loop)."""
-        t_c = time.perf_counter()
         self._commit_step(st, pending)
-        t_end = time.perf_counter()
-        st.last_commit_s = t_end - t_c
-        dt = t_end - pending.t_start
+        dt = time.perf_counter() - pending.t_start
         window = st.durations[-self.straggler_window:]
         if len(window) >= 5:
             med = statistics.median(window)
@@ -1418,10 +1446,10 @@ class ServeEngine:
         (the non-fused fallback used to fetch the tuple piecewise)."""
         needed = max(st.slot_pos[s] for s in st.live) + 1
         attend = self._attend_len(needed)
+        self._count_decode(st, 1, attend if self.fused else self.max_seq)
         nan_mask = self._nan_mask(st)
         if self.fused and st.mgr is not None:
-            if st.mgr.dirty:
-                st.bt_dev = st.mgr.device_tables()
+            self._upload_tables(st)
             (st.pool, st.tok, st.pos, st.remaining, done,
              bad) = self._paged_step(
                 self.params, st.pool, st.bt_dev, st.tok, st.pos,
@@ -1451,8 +1479,8 @@ class ServeEngine:
         t_w = self.spec_k
         needed = max(st.slot_pos[s] for s in st.live) + t_w
         attend = self._attend_len(needed)
-        if st.mgr.dirty:
-            st.bt_dev = st.mgr.device_tables()
+        self._count_decode(st, t_w, attend)
+        self._upload_tables(st)
         (st.pool, st.draft_cache, targets, commit, st.tok, st.pos,
          st.remaining, done, bad) = self._spec_step(
             self.params, self.draft_params, st.pool, st.draft_cache,
@@ -1460,6 +1488,31 @@ class ServeEngine:
             self._nan_mask(st), self._collapse_mask(st), attend)
         return PendingRound(arrays=(targets, commit, done, bad),
                             live=dict(st.live), spec=True)
+
+    def _count_decode(self, st: "_SchedState", window: int, grid: int):
+        """Decode counters, where the step's shapes are chosen: the keys
+        each live row needs (``slot_pos + window``) and what the
+        attention grid covers, every slot over the ``grid``-token bucket.
+        ``decode_grid_tokens`` reads the grid as it is today: a change to
+        the kernel's grid changes this counter."""
+        c = st.counters
+        c["decode_steps"] += 1
+        c["decode_ctx_tokens"] += sum(st.slot_pos[s] + window
+                                      for s in st.live)
+        c["decode_grid_tokens"] += self.slots * grid
+
+    def _count_prefill(self, st: "_SchedState", n: int, start: int = 0):
+        """Prefill counters: ``n`` uncached prompt tokens computed after
+        ``start`` cached ones, and the keys their causal rows attend."""
+        c = st.counters
+        c["prefill_tokens"] += n
+        c["prefill_causal_keys"] += n * start + n * (n + 1) // 2
+
+    def _upload_tables(self, st: "_SchedState"):
+        """Upload the block tables if the allocator changed them."""
+        if st.mgr.dirty:
+            with span(st, "serve.tables"):
+                st.bt_dev = st.mgr.device_tables()
 
     def _step(self, st: "_SchedState"):
         """Serial dispatch + commit in one call (kept for direct
@@ -1475,47 +1528,53 @@ class ServeEngine:
         dispatch."""
         if pending.spec:
             return self._commit_spec(st, pending)
-        nxt_h, done_h, bad_h = jax.device_get(pending.arrays)
-        now = time.perf_counter() - st.t0
-        for slot, req in list(pending.live.items()):
-            if bool(bad_h[slot]):
-                # NaN quarantine: fail the offending request only — no
-                # token appended, the rest of the batch commits normally
-                self._terminal(st, req, STATUS_FAILED, slot=slot,
-                               reason="nan-logits")
-                continue
-            req.generated.append(int(nxt_h[slot]))
-            st.slot_pos[slot] += 1
-            self._record_tbt(st, req.uid, now, 1)
-            if bool(done_h[slot]):
-                self._finish(st, slot, now)
+        with span(st, "serve.fetch"):
+            nxt_h, done_h, bad_h = jax.device_get(pending.arrays)
+        with span(st, "serve.commit"):
+            now = time.perf_counter() - st.t0
+            for slot, req in list(pending.live.items()):
+                if bool(bad_h[slot]):
+                    # NaN quarantine: fail the offending request only —
+                    # no token appended, the rest of the batch commits
+                    self._terminal(st, req, STATUS_FAILED, slot=slot,
+                                   reason="nan-logits")
+                    continue
+                req.generated.append(int(nxt_h[slot]))
+                st.slot_pos[slot] += 1
+                self._record_tbt(st, req.uid, now, 1)
+                if bool(done_h[slot]):
+                    self._finish(st, slot, now)
 
     def _commit_spec(self, st: "_SchedState", pending: PendingRound):
         """Commit half of a speculative window: append the committed
         prefix, then retract pages holding only rejected rows (table
         edit)."""
-        targets_h, commit_h, done_h, bad_h = jax.device_get(pending.arrays)
-        now = time.perf_counter() - st.t0
-        for slot, req in list(pending.live.items()):
-            if bool(bad_h[slot]):
-                self._terminal(st, req, STATUS_FAILED, slot=slot,
-                               reason="nan-logits")
-                continue
-            c = int(commit_h[slot])
-            req.generated.extend(int(x) for x in targets_h[slot, :c])
-            st.slot_pos[slot] += c
-            self._record_tbt(st, req.uid, now, c)
-            s = st.stats[req.uid]
-            s["spec_steps"] = s.get("spec_steps", 0) + 1
-            s["spec_tokens"] = s.get("spec_tokens", 0) + c
-            self._spec_governor(st, slot, req, c)
-            if bool(done_h[slot]):
-                self._finish(st, slot, now)
-            else:
-                # write-then-retract: pages mapped for the window whose
-                # rows were all rejected go back to the allocator
-                st.mgr.retract_above(slot, st.slot_pos[slot])
-        self._spec_cooldown_tick(st)
+        with span(st, "serve.fetch"):
+            targets_h, commit_h, done_h, bad_h = jax.device_get(
+                pending.arrays)
+        with span(st, "serve.commit"):
+            now = time.perf_counter() - st.t0
+            for slot, req in list(pending.live.items()):
+                if bool(bad_h[slot]):
+                    self._terminal(st, req, STATUS_FAILED, slot=slot,
+                                   reason="nan-logits")
+                    continue
+                c = int(commit_h[slot])
+                req.generated.extend(int(x) for x in targets_h[slot, :c])
+                st.slot_pos[slot] += c
+                self._record_tbt(st, req.uid, now, c)
+                s = st.stats[req.uid]
+                s["spec_steps"] = s.get("spec_steps", 0) + 1
+                s["spec_tokens"] = s.get("spec_tokens", 0) + c
+                self._spec_governor(st, slot, req, c)
+                if bool(done_h[slot]):
+                    self._finish(st, slot, now)
+                else:
+                    # write-then-retract: pages mapped for the window
+                    # whose rows were all rejected go back to the
+                    # allocator
+                    st.mgr.retract_above(slot, st.slot_pos[slot])
+            self._spec_cooldown_tick(st)
 
     def _spec_governor(self, st: "_SchedState", slot: int, req: Request,
                        committed: int):
@@ -1765,12 +1824,14 @@ class ServeEngine:
         toks = np.zeros((1, t_b), np.int32)
         toks[0, :len(chunk)] = chunk
         attend = self._attend_len(cs.done + t_b)
-        if st.mgr.dirty:
-            st.bt_dev = st.mgr.device_tables()
-        logits, st.pool = self._suffix_prefill(
-            self.params, st.pool, st.bt_dev[slot:slot + 1],
-            jnp.asarray(toks), jnp.asarray([cs.done], jnp.int32),
-            jnp.asarray([len(chunk) - 1], jnp.int32), attend)
+        self._upload_tables(st)
+        self._count_prefill(st, len(chunk), cs.done)
+        with span(st, "serve.prefill", uid=req.uid, tokens=len(chunk),
+                  bucket=t_b):
+            logits, st.pool = self._suffix_prefill(
+                self.params, st.pool, st.bt_dev[slot:slot + 1],
+                jnp.asarray(toks), jnp.asarray([cs.done], jnp.int32),
+                jnp.asarray([len(chunk) - 1], jnp.int32), attend)
         cs.done += len(chunk)
         s = st.stats[req.uid]
         s["prefill_chunks"] = s.get("prefill_chunks", 0) + 1
@@ -1837,7 +1898,8 @@ class ServeEngine:
                    st.rnd if st.faults is not None else None)
             if st.gate_block == key:
                 break
-            plan = st.mgr.plan_admit(req.prompt)
+            with span(st, "serve.prefix_plan"):
+                plan = st.mgr.plan_admit(req.prompt)
             if (not st.mgr.can_admit_plan(plan,
                                           headroom=self._headroom(st, 0))
                     or st.mgr.admit_prefix(slot, plan) is None):
@@ -1867,22 +1929,25 @@ class ServeEngine:
         un-cached suffix through the paged cache (bucketed window — the
         shared prefix is read through the block tables, never copied)."""
         if plan.cow_src is not None:
-            st.pool = copy_pages(st.pool,
-                                 jnp.asarray([plan.cow_src], jnp.int32),
-                                 jnp.asarray([plan.cow_dst], jnp.int32))
+            with span(st, "serve.scatter"):
+                st.pool = copy_pages(
+                    st.pool, jnp.asarray([plan.cow_src], jnp.int32),
+                    jnp.asarray([plan.cow_dst], jnp.int32))
         st.mgr.cow_release(plan)  # the fork-source pin outlives the copy
         suffix = req.prompt[plan.cached_tokens:]
         t_b = _round_up(len(suffix), self.prompt_block)
         toks = np.zeros((1, t_b), np.int32)
         toks[0, :len(suffix)] = suffix
         attend = self._attend_len(plan.cached_tokens + t_b)
-        if st.mgr.dirty:
-            st.bt_dev = st.mgr.device_tables()
-        logits, st.pool = self._suffix_prefill(
-            self.params, st.pool, st.bt_dev[slot:slot + 1],
-            jnp.asarray(toks),
-            jnp.asarray([plan.cached_tokens], jnp.int32),
-            jnp.asarray([len(suffix) - 1], jnp.int32), attend)
+        self._upload_tables(st)
+        self._count_prefill(st, len(suffix), plan.cached_tokens)
+        with span(st, "serve.prefill", uid=req.uid, tokens=len(suffix),
+                  bucket=t_b):
+            logits, st.pool = self._suffix_prefill(
+                self.params, st.pool, st.bt_dev[slot:slot + 1],
+                jnp.asarray(toks),
+                jnp.asarray([plan.cached_tokens], jnp.int32),
+                jnp.asarray([len(suffix) - 1], jnp.int32), attend)
         if self.spec_k > 1:
             # the draft cache is a dense slot pool with no sharing: it
             # prefills the full prompt (draft quality only affects the
@@ -1912,7 +1977,9 @@ class ServeEngine:
                                 jnp.asarray([r.uid for r in reqs],
                                             jnp.int32))
         finite = jnp.all(jnp.isfinite(logits), axis=-1)
-        first_h, finite_h = jax.device_get((first, finite))
+        with span(st, "serve.prefill_fetch"):
+            first_h, finite_h = jax.device_get((first, finite))
+        st.counters["admissions"] += len(reqs)
         slot_idx = jnp.asarray(slots, jnp.int32)
         st.pos = st.pos.at[slot_idx].set(jnp.asarray(lens, jnp.int32))
         st.tok = st.tok.at[slot_idx].set(first)
@@ -1957,23 +2024,32 @@ class ServeEngine:
         for i, r in enumerate(reqs):
             toks[i, :lens[i]] = r.prompt
         last_pos = jnp.asarray([l - 1 for l in lens], jnp.int32)
+        for n in lens:
+            self._count_prefill(st, n)
+        prefill = span(st, "serve.prefill",
+                       uid=" ".join(str(r.uid) for r in reqs),
+                       tokens=sum(lens), bucket=bucket)
         if st.mgr is not None:
-            logits, pcache = self._prefill_bucket(
-                self.params, {"tokens": jnp.asarray(toks)}, last_pos)
+            with prefill:
+                logits, pcache = self._prefill_bucket(
+                    self.params, {"tokens": jnp.asarray(toks)}, last_pos)
             n_blocks = cdiv(bucket, self.page_size)
             page_idx = np.stack([st.mgr.prefill_page_idx(s, n_blocks)
                                  for s in slots])
-            st.pool = scatter_prefill(
-                st.pool, {"k": pcache["k"], "v": pcache["v"]},
-                jnp.asarray(page_idx))
+            with span(st, "serve.scatter"):
+                st.pool = scatter_prefill(
+                    st.pool, {"k": pcache["k"], "v": pcache["v"]},
+                    jnp.asarray(page_idx))
         else:
-            logits, pcache = self._prefill_padded(
-                self.params, {"tokens": jnp.asarray(toks)}, last_pos)
-            slot_idx = jnp.asarray(slots, jnp.int32)
-            if len(group) == 1:
-                st.cache = write_slot(st.cache, pcache, slots[0])
-            else:
-                st.cache = write_slots(st.cache, pcache, slot_idx)
+            with prefill:
+                logits, pcache = self._prefill_padded(
+                    self.params, {"tokens": jnp.asarray(toks)}, last_pos)
+            with span(st, "serve.scatter"):
+                if len(group) == 1:
+                    st.cache = write_slot(st.cache, pcache, slots[0])
+                else:
+                    st.cache = write_slots(st.cache, pcache,
+                                           jnp.asarray(slots, jnp.int32))
         if self.spec_k > 1:
             # the draft proposes from its own cache: prefill it alongside
             # the target (same padded batch; draft logits are discarded —
@@ -1998,15 +2074,16 @@ class ServeEngine:
         class still holding a slot (LIFO within a class — the oldest
         always makes progress) and requeue it at the queue front with its
         generated tokens folded into its prompt."""
-        span = self.spec_k
-        for slot in sorted(st.live, key=lambda s: st.admit_seq[s]):
-            if slot not in st.live:
-                continue  # preempted while serving an older slot
-            while slot in st.live:
-                first = st.slot_pos[slot]
-                if st.mgr.ensure_span(slot, first, first + span - 1):
-                    break
-                self._preempt(st, self._preempt_victim(st))
+        with span(st, "serve.grow"):
+            for slot in sorted(st.live, key=lambda s: st.admit_seq[s]):
+                if slot not in st.live:
+                    continue  # preempted while serving an older slot
+                while slot in st.live:
+                    first = st.slot_pos[slot]
+                    if st.mgr.ensure_span(slot, first,
+                                          first + self.spec_k - 1):
+                        break
+                    self._preempt(st, self._preempt_victim(st))
 
     def _slack_ms(self, st: "_SchedState", req: Request,
                   now_ms: float) -> float:
@@ -2075,11 +2152,12 @@ class ServeEngine:
             # D2H materialization is deferred to the next commit boundary
             # (the device slice is issued now; JAX value semantics keep
             # the data alive) so a swap victim never stalls the next
-            # dispatch; serial keeps the copy synchronous.
+            # dispatch; serial materializes it at once.
             handle = st.mgr.swap_out(slot, st.pool, st.slot_pos[slot],
-                                     async_copy=self.pipeline)
-            if self.pipeline:
-                st.pending_swaps.append(handle)
+                                     async_copy=True)
+            st.pending_swaps.append(handle)
+            if not self.pipeline:
+                self._drain_swaps(st)
             st.swaps[req.uid] = handle
             s = st.stats[req.uid]
             s["swap_outs"] = s.get("swap_outs", 0) + 1
@@ -2165,12 +2243,14 @@ class _ChunkState:
 
 
 def _empty_timeseries() -> Dict[str, list]:
+    # per round: one column per span of repro.serve.trace (host seconds
+    # of that phase, e.g. dispatch_s, fetch_s, commit_s), overlap_s (the
+    # sweep's seconds when it ran while a step was in flight; 0.0 when
+    # serial), and one cumulative column per counter
     return {"t_s": [], "round": [], "queue_depth": [], "live_slots": [],
-            "utilization": [], "free_pages": [],
-            # per-round pipeline phases: host time issuing the dispatch,
-            # host time blocked in the commit fetch, and host work done
-            # in the gap while a step was in flight (0.0 when serial)
-            "dispatch_s": [], "commit_s": [], "overlap_s": []}
+            "utilization": [], "free_pages": [], "overlap_s": [],
+            **{column(name): [] for name in SPANS},
+            **{name: [] for name in COUNTERS}}
 
 
 @dataclasses.dataclass
@@ -2231,6 +2311,8 @@ class _SchedState:
     #                                            not yet committed)
     pending_swaps: List[SwapHandle] = dataclasses.field(
         default_factory=list)  # async swap-outs awaiting materialization
-    last_dispatch_s: float = 0.0   # this round's phase timings
-    last_commit_s: float = 0.0     # (reset at every round tick)
-    last_overlap_s: float = 0.0
+    overlapped: bool = False   # this round's sweep ran under a step
+    # ---- spans and counters (repro.serve.trace)
+    phase_s: Dict[str, float] = dataclasses.field(default_factory=new_phases)
+    counters: Dict[str, int] = dataclasses.field(
+        default_factory=lambda: dict.fromkeys(COUNTERS, 0))

@@ -58,10 +58,11 @@ def summarize(stats: Dict[Any, Any], *, tbt_s: List[float],
     ``tbt_s``: raw time-between-token gap samples, seconds.
     ``wall_s``: session wall time, the goodput denominator.
     ``timeseries``: optional per-round engine timeseries; when it
-    carries the pipeline phase columns (``dispatch_s`` / ``commit_s`` /
-    ``overlap_s``) the summary gains a ``rounds`` block with their
-    means — how much host work ran inside the dispatch, blocked on the
-    commit fetch, and was hidden under an in-flight device step.
+    carries the phase columns (``dispatch_s`` / ``fetch_s`` /
+    ``commit_s`` / ``overlap_s``) the summary gains a ``rounds`` block
+    with their means — how much host time went to issuing the decode
+    step, was spent blocked in its fetch, went to the accounting after
+    it, and was hidden under an in-flight device step.
     """
     per = {u: s for u, s in stats.items() if isinstance(u, int)}
     ttft = [s["first_token_s"] - s.get("enqueued_s", 0.0)
@@ -84,7 +85,7 @@ def summarize(stats: Dict[Any, Any], *, tbt_s: List[float],
     }
     if timeseries and timeseries.get("round"):
         rounds: Dict[str, Any] = {"n": len(timeseries["round"])}
-        for col in ("dispatch_s", "commit_s", "overlap_s"):
+        for col in ("dispatch_s", "fetch_s", "commit_s", "overlap_s"):
             vals = timeseries.get(col) or []
             rounds[f"{col}_mean"] = (float(np.mean(vals)) if vals
                                      else None)

@@ -177,18 +177,25 @@ def test_pipeline_parity_cluster_disaggregated():
 
 
 def test_pipeline_timeseries_phases():
-    """The pipelined timeseries reports dispatch/commit/overlap phase
-    timings and the SLA summary rolls them up."""
+    """The pipelined timeseries reports dispatch/fetch/commit/overlap
+    phase timings from the engine's spans, and the SLA summary rolls
+    them up.  The fetch (the blocking wait) and the commit (host
+    accounting after it) are separate phases."""
     eng = _engine(pipeline=True)
     eng.serve(_fresh(_reqs(4)))
     ts = eng.last_stats["timeseries"]
     n = len(ts["round"])
-    assert len(ts["dispatch_s"]) == len(ts["commit_s"]) \
-        == len(ts["overlap_s"]) == n
+    assert len(ts["dispatch_s"]) == len(ts["fetch_s"]) \
+        == len(ts["commit_s"]) == len(ts["overlap_s"]) == n
     assert any(v > 0 for v in ts["overlap_s"])
+    assert sum(ts["fetch_s"]) > 0 and sum(ts["commit_s"]) > 0
+    # every phase lies inside its round
+    for col in ("dispatch_s", "fetch_s", "commit_s", "overlap_s"):
+        assert all(v <= r for v, r in zip(ts[col], ts["round_s"]))
     rounds = eng.last_stats["sla"]["rounds"]
     assert rounds["n"] == n
     assert rounds["overlap_s_mean"] > 0
+    assert rounds["fetch_s_mean"] > 0
     # serial rounds never report overlap
     eng = _engine(pipeline=False)
     eng.serve(_fresh(_reqs(4)))
