@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from repro.kernels.decode_attention.decode_attention import (
     flash_decode,
     paged_flash_decode,
+    stack_pools,
 )
 
 
@@ -44,21 +45,26 @@ def paged_decode_attention_op(q: jnp.ndarray, k_pages: jnp.ndarray,
                               block_tables: jnp.ndarray, pos: jnp.ndarray,
                               k_scales: Optional[jnp.ndarray] = None,
                               v_scales: Optional[jnp.ndarray] = None,
+                              layer=0, lengths: Optional[jnp.ndarray] = None,
                               interpret: Optional[bool] = None
                               ) -> jnp.ndarray:
-    """q: (B, 1, Hq, D); pages (P, page_size, Hkv, Dv); block_tables
-    (B, NB) physical page per logical block; pos (B,).
+    """q: (B, 1, Hq, D); pages (L, P, page_size, Hkv, Dv) read at
+    ``layer``, or one layer's (P, page_size, Hkv, Dv); block_tables
+    (B, NB) physical page per logical block; pos (B,); ``lengths`` (B,)
+    keys each row reads (0: a free row), ``None`` for ``pos + 1``.
 
-    Returns (B, 1, Hq, Dv).  The kv block size is the page size — one
-    page per grid step, gathered through the scalar-prefetched table.
-    ``k_scales``/``v_scales`` ((P, page_size) float32) mark int8 pages;
-    dequant fuses into the kernel's gather."""
+    Returns (B, 1, Hq, Dv).  The kernel copies each row's pages out of
+    the pool itself, several pages a compute block.
+    ``k_scales``/``v_scales`` ((L, P, page_size) or (P, page_size)
+    float32) mark int8 pages; dequant fuses into the kernel."""
+    k_pages, v_pages, k_scales, v_scales = stack_pools(
+        k_pages, v_pages, k_scales, v_scales)
     b, _, hq, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[3]
     dv = v_pages.shape[-1]
     g = hq // hkv
     qg = q.reshape(b, hkv, g, d)
     o = paged_flash_decode(qg, k_pages, v_pages, block_tables, pos,
-                           k_scales=k_scales, v_scales=v_scales,
-                           interpret=interpret)
+                           layer=layer, lengths=lengths, k_scales=k_scales,
+                           v_scales=v_scales, interpret=interpret)
     return o.reshape(b, 1, hq, dv)

@@ -161,60 +161,22 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     return o.reshape(b, 1, hq, d).astype(q.dtype)
 
 
-def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
-                           v_pages: jnp.ndarray,
-                           block_tables: jnp.ndarray, pos: jnp.ndarray, *,
-                           attend_len: Optional[int] = None,
-                           k_scales: Optional[jnp.ndarray] = None,
-                           v_scales: Optional[jnp.ndarray] = None,
-                           backend: Optional[str] = None) -> jnp.ndarray:
-    """One-token decode against a *paged* cache: q (B, 1, Hq, D), page
-    pools (P, page_size, Hkv, D), block_tables (B, NB) mapping logical
-    block j -> physical page, pos (B,) (positions <= pos valid).
+def _paged_rows(block_tables, pos, t_window: int, page_size: int):
+    """Keys each row of a paged read needs: ``pos + t_window``, within
+    the table; 0 for a free row — one whose table starts at the trash
+    page (the allocator points every released slot's whole row there)."""
+    from repro.serve.kv_cache import TRASH_PAGE
 
-    This is the layout half of the paper's HW-vs-SW axis: the dense
-    :func:`decode_attention` reads a contiguous prefix (the HW path —
-    addresses are affine in position), while the paged read must resolve
-    every block through the table.  Two lowerings:
+    need = jnp.minimum(pos + t_window, block_tables.shape[1] * page_size)
+    return jnp.where(block_tables[:, 0] == TRASH_PAGE, 0, need)
 
-      'kernel'  paged flash-decode Pallas kernel — the table rides the
-                scalar-prefetch channel, so the indirection costs an SMEM
-                lookup per block, not a materialized gather;
-      'jnp'     ``jnp.take`` block gather into a dense view, then the
-                dense SW softmax — the CPU fallback *and* the
-                paper-analogue SW emulation cost (the gather round-trips
-                the gathered pages through memory).
 
-    attend_len: static bound on the valid prefix; only the first
-    ceil(attend_len / page_size) table columns are visited.
-
-    k_scales/v_scales ((P, page_size) float32, both or neither): the pages
-    are int8-quantized with per-row symmetric scales.  Both lowerings
-    dequantize inside the gather — the kernel multiplies the scale block
-    streamed through the same table index map; the jnp path ``jnp.take``s
-    the scales with the same truncated table and broadcasts them over the
-    gathered rows — so the kernel-vs-SW parity axis extends unchanged to
-    the quantized tier.
-    """
-    page_size = k_pages.shape[1]
-    nb = block_tables.shape[1]
-    if (k_scales is None) != (v_scales is None):
-        raise ValueError("pass both k_scales and v_scales or neither")
-    if attend_len is not None:
-        nb = min(nb, -(-attend_len // page_size))
-        block_tables = block_tables[:, :nb]
-    if backend is None:
-        backend = default_decode_backend()
-    if backend == "kernel":
-        from repro.kernels.decode_attention.ops import (
-            paged_decode_attention_op,
-        )
-
-        return paged_decode_attention_op(q, k_pages, v_pages, block_tables,
-                                         pos, k_scales=k_scales,
-                                         v_scales=v_scales)
-    b = q.shape[0]
-    hkv, d = k_pages.shape[2], k_pages.shape[3]
+def _gather_pages(k_pages, v_pages, block_tables, k_scales, v_scales):
+    """The SW indirection: ``jnp.take`` every table entry's page into a
+    dense (B, NB*page_size, H, D) view, dequantizing int8 pages with their
+    per-row scales taken through the same table."""
+    b, nb = block_tables.shape
+    page_size, hkv, d = k_pages.shape[1:]
     dv = v_pages.shape[-1]
     k = jnp.take(k_pages, block_tables.reshape(-1), axis=0)
     v = jnp.take(v_pages, block_tables.reshape(-1), axis=0)
@@ -223,14 +185,95 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         vs = jnp.take(v_scales, block_tables.reshape(-1), axis=0)
         k = k.astype(jnp.float32) * ks[..., None, None]
         v = v.astype(jnp.float32) * vs[..., None, None]
-    k = k.reshape(b, nb * page_size, hkv, d)
-    v = v.reshape(b, nb * page_size, hkv, dv)
+    return (k.reshape(b, nb * page_size, hkv, d),
+            v.reshape(b, nb * page_size, hkv, dv))
+
+
+def _paged_args(k_pages, v_pages, block_tables, k_scales, v_scales,
+                attend_len, layer):
+    """Checks shared by the paged reads; returns the page size and the
+    table cut to the ``attend_len`` bucket."""
+    if (k_scales is None) != (v_scales is None):
+        raise ValueError("pass both k_scales and v_scales or neither")
+    if (layer is None) != (k_pages.ndim == 4):
+        raise ValueError("stacked (L, P, page, H, D) pools take a layer; "
+                         "one layer's (P, page, H, D) pools take none")
+    page_size = k_pages.shape[-3]
+    if attend_len is not None:
+        nb = min(block_tables.shape[1], -(-attend_len // page_size))
+        block_tables = block_tables[:, :nb]
+    return page_size, block_tables
+
+
+def _one_layer(layer, *pools):
+    """``layer``'s slice of each stacked pool (the jnp path's read)."""
+    return [p if p is None or layer is None else p[layer] for p in pools]
+
+
+def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
+                           v_pages: jnp.ndarray,
+                           block_tables: jnp.ndarray, pos: jnp.ndarray, *,
+                           layer=None,
+                           attend_len: Optional[int] = None,
+                           k_scales: Optional[jnp.ndarray] = None,
+                           v_scales: Optional[jnp.ndarray] = None,
+                           backend: Optional[str] = None) -> jnp.ndarray:
+    """One-token decode against a *paged* cache: q (B, 1, Hq, D), page
+    pools (L, P, page_size, Hkv, D) read at ``layer`` (or one layer's
+    (P, page_size, Hkv, D) with ``layer`` None), block_tables (B, NB)
+    mapping logical block j -> physical page, pos (B,) (positions <= pos
+    valid).
+
+    This is the layout half of the paper's HW-vs-SW axis: the dense
+    :func:`decode_attention` reads a contiguous prefix (the HW path —
+    addresses are affine in position), while the paged read must resolve
+    every block through the table.  Two lowerings:
+
+      'kernel'  paged flash-decode Pallas kernel — the table rides the
+                scalar-prefetch channel and the kernel copies each live
+                page out of the pool where it lives, several pages a
+                compute block; a row whose table starts at the trash page
+                (a free slot) reads and computes nothing, and its output
+                is zeros;
+      'jnp'     ``jnp.take`` block gather into a dense view, then the
+                dense SW softmax — the CPU fallback *and* the
+                paper-analogue SW emulation cost (the gather round-trips
+                the gathered pages through memory).
+
+    attend_len: static bound on the valid prefix; only the first
+    ceil(attend_len / page_size) table columns are visited.
+
+    k_scales/v_scales ((L, P, page_size) or (P, page_size) float32, both
+    or neither): the pages are int8-quantized with per-row symmetric
+    scales.  Both lowerings dequantize inside the gather — the kernel
+    scales scores and probabilities by the scales of the keys it reads;
+    the jnp path ``jnp.take``s the scales with the same truncated table
+    and broadcasts them over the gathered rows — so the kernel-vs-SW
+    parity axis extends unchanged to the quantized tier.
+    """
+    page_size, block_tables = _paged_args(k_pages, v_pages, block_tables,
+                                          k_scales, v_scales, attend_len,
+                                          layer)
+    if backend is None:
+        backend = default_decode_backend()
+    if backend == "kernel":
+        from repro.kernels.decode_attention.ops import (
+            paged_decode_attention_op,
+        )
+
+        return paged_decode_attention_op(
+            q, k_pages, v_pages, block_tables, pos, k_scales=k_scales,
+            v_scales=v_scales, layer=0 if layer is None else layer,
+            lengths=_paged_rows(block_tables, pos, 1, page_size))
+    k, v = _gather_pages(*_one_layer(layer, k_pages, v_pages), block_tables,
+                         *_one_layer(layer, k_scales, v_scales))
     return decode_attention(q, k, v, pos, backend="jnp")
 
 
 def paged_verify_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                            v_pages: jnp.ndarray,
                            block_tables: jnp.ndarray, pos: jnp.ndarray, *,
+                           layer=None,
                            attend_len: Optional[int] = None,
                            k_scales: Optional[jnp.ndarray] = None,
                            v_scales: Optional[jnp.ndarray] = None,
@@ -238,8 +281,9 @@ def paged_verify_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     """k-token speculative verify against the paged cache: q (B, T, Hq, D)
     is the draft window's queries at absolute positions pos..pos+T-1 (whose
     K/V rows are already written through the block tables), page pools
-    (P, page_size, Hkv, Dv), block_tables (B, NB), pos (B,) first window
-    position.  Returns (B, T, Hq, Dv).
+    (L, P, page_size, Hkv, Dv) read at ``layer`` (or one layer's pools with
+    ``layer`` None), block_tables (B, NB), pos (B,) first window position.
+    Returns (B, T, Hq, Dv).
 
     Causal masking *within the window* is per-row: query t attends cache
     positions <= pos+t.  T=1 is exactly single-token paged decode.  Two
@@ -248,8 +292,10 @@ def paged_verify_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
       'kernel'  fused flash-verify Pallas kernel
                 (``repro.kernels.verify_attention``): ONE dispatch scores
                 all T positions, block table on the scalar-prefetch
-                channel, online softmax in VMEM scratch — the k-for-1
-                dispatch amortization;
+                channel, pages copied out of the pool by the kernel,
+                online softmax in VMEM scratch — the k-for-1 dispatch
+                amortization; free rows as in
+                :func:`paged_decode_attention`;
       'jnp'     ``jnp.take`` block gather into a dense view + per-row
                 dense-masked softmax over the window — the chunked SW
                 verification baseline (and CPU fallback).  Structurally
@@ -260,44 +306,33 @@ def paged_verify_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     attend_len: static bound on ``pos + T`` (engine-side bucketing); only
     the first ceil(attend_len / page_size) table columns are visited.
 
-    k_scales/v_scales ((P, page_size) float32, both or neither): int8
-    pages with per-row symmetric scales, dequantized inside the gather on
-    both lowerings (see :func:`paged_decode_attention`).
+    k_scales/v_scales (float32, both or neither): int8 pages with per-row
+    symmetric scales, dequantized inside the gather on both lowerings (see
+    :func:`paged_decode_attention`).
     """
-    page_size = k_pages.shape[1]
-    nb = block_tables.shape[1]
-    if (k_scales is None) != (v_scales is None):
-        raise ValueError("pass both k_scales and v_scales or neither")
-    if attend_len is not None:
-        nb = min(nb, -(-attend_len // page_size))
-        block_tables = block_tables[:, :nb]
+    page_size, block_tables = _paged_args(k_pages, v_pages, block_tables,
+                                          k_scales, v_scales, attend_len,
+                                          layer)
     if backend is None:
         backend = default_decode_backend()
+    b, t, hq, d = q.shape
     if backend == "kernel":
         from repro.kernels.verify_attention.ops import (
             paged_verify_attention_op,
         )
 
-        return paged_verify_attention_op(q, k_pages, v_pages, block_tables,
-                                         pos, k_scales=k_scales,
-                                         v_scales=v_scales)
-    b, t, hq, d = q.shape
-    hkv = k_pages.shape[2]
-    dv = v_pages.shape[-1]
+        return paged_verify_attention_op(
+            q, k_pages, v_pages, block_tables, pos, k_scales=k_scales,
+            v_scales=v_scales, layer=0 if layer is None else layer,
+            lengths=_paged_rows(block_tables, pos, t, page_size))
+    k, v = _gather_pages(*_one_layer(layer, k_pages, v_pages), block_tables,
+                         *_one_layer(layer, k_scales, v_scales))
+    hkv, dv = k.shape[2], v.shape[-1]
     g = hq // hkv
-    k = jnp.take(k_pages, block_tables.reshape(-1), axis=0)
-    v = jnp.take(v_pages, block_tables.reshape(-1), axis=0)
-    if k_scales is not None:
-        ks = jnp.take(k_scales, block_tables.reshape(-1), axis=0)
-        vs = jnp.take(v_scales, block_tables.reshape(-1), axis=0)
-        k = k.astype(jnp.float32) * ks[..., None, None]
-        v = v.astype(jnp.float32) * vs[..., None, None]
-    k = k.reshape(b, nb * page_size, hkv, d)
-    v = v.reshape(b, nb * page_size, hkv, dv)
     qg = q.reshape(b, t, hkv, g, d)
     s = jnp.einsum("bthgd,bkhd->bhtgk", qg.astype(jnp.float32),
                    k.astype(jnp.float32)) * (d ** -0.5)
-    ki = jnp.arange(nb * page_size)
+    ki = jnp.arange(k.shape[1])
     row_limit = pos[:, None] + jnp.arange(t)[None, :]        # (B, T)
     valid = ki[None, None, :] <= row_limit[:, :, None]       # (B, T, K)
     s = jnp.where(valid[:, None, :, None, :], s, NEG_INF)

@@ -684,14 +684,12 @@ class Model:
                 vp = vp.at[l, page, off].set(qv.astype(vp.dtype))
                 ks = ks.at[l, page, off].set(sk)
                 vs = vs.at[l, page, off].set(sv)
-                return paged_decode_attention(q, kp[l], vp[l], bt, pos,
-                                              attend_len=attend_len,
-                                              k_scales=ks[l], v_scales=vs[l],
-                                              backend=self.decode_backend)
-            kp = kp.at[l, page, off].set(k[:, 0].astype(kp.dtype))
-            vp = vp.at[l, page, off].set(v[:, 0].astype(vp.dtype))
-            return paged_decode_attention(q, kp[l], vp[l], bt, pos,
+            else:
+                kp = kp.at[l, page, off].set(k[:, 0].astype(kp.dtype))
+                vp = vp.at[l, page, off].set(v[:, 0].astype(vp.dtype))
+            return paged_decode_attention(q, kp, vp, bt, pos, layer=l,
                                           attend_len=attend_len,
+                                          k_scales=ks, v_scales=vs,
                                           backend=self.decode_backend)
 
         logits = self._gqa_decode_loop(params, x, pos, write_attend)
@@ -772,14 +770,12 @@ class Model:
                 vp = vp.at[l, page, off].set(qv.astype(vp.dtype))
                 ks = ks.at[l, page, off].set(sk)
                 vs = vs.at[l, page, off].set(sv)
-                return paged_verify_attention(q, kp[l], vp[l], bt, pos,
-                                              attend_len=attend_len,
-                                              k_scales=ks[l], v_scales=vs[l],
-                                              backend=backend)
-            kp = kp.at[l, page, off].set(k.astype(kp.dtype))
-            vp = vp.at[l, page, off].set(v.astype(vp.dtype))
-            return paged_verify_attention(q, kp[l], vp[l], bt, pos,
+            else:
+                kp = kp.at[l, page, off].set(k.astype(kp.dtype))
+                vp = vp.at[l, page, off].set(v.astype(vp.dtype))
+            return paged_verify_attention(q, kp, vp, bt, pos, layer=l,
                                           attend_len=attend_len,
+                                          k_scales=ks, v_scales=vs,
                                           backend=backend)
 
         x = self._gqa_decode_layers(params, x, positions, write_attend)

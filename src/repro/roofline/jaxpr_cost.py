@@ -138,6 +138,8 @@ def _pallas_block_traffic(eqn) -> float:
         points = [p + (i,) for p in points for i in range(g)]
     total = 0.0
     for bm in gm.block_mappings:
+        if _in_place(bm):
+            continue
         # ref_aval is the block as the kernel sees it (squeezed dims
         # dropped): same element count as the transferred block
         block_bytes = float(np.prod(bm.ref_aval.shape)
@@ -153,6 +155,47 @@ def _pallas_block_traffic(eqn) -> float:
                 fetches += 1
                 prev = idx
         total += fetches * block_bytes
+    return total
+
+
+def _in_place(bm) -> bool:
+    """An operand left where it lives (``memory_space=pl.ANY``): the
+    pipeline moves none of it; the kernel's own copies are charged by
+    :func:`_dma_bytes_in`."""
+    return "any" in str(getattr(bm.block_aval, "memory_space", "")).lower()
+
+
+def _dma_bytes(eqn) -> float:
+    """Bytes one ``dma_start`` moves: the slice its source indexer
+    selects."""
+    from jax._src.pallas.mosaic.primitives import _dma_unflatten
+
+    src, src_transforms, *_ = _dma_unflatten(
+        eqn.params["tree"], [v.aval for v in eqn.invars])
+    shape = (src_transforms[-1].get_indexer_shape() if src_transforms
+             else src.shape)
+    return float(np.prod(shape)) * np.dtype(src.dtype).itemsize
+
+
+def _dma_bytes_in(jaxpr) -> float:
+    """HBM bytes a kernel body's own async copies move in one grid step:
+    loop bodies count once per trip, a ``cond`` (``pl.when``) at its
+    costliest branch — so a copy the kernel skips for a short row is
+    still charged, the conservative full-length traffic of the replay."""
+    total = 0.0
+    for eqn in jaxpr.eqns:
+        prim, params = eqn.primitive.name, eqn.params
+        if prim == "dma_start":
+            total += _dma_bytes(eqn)
+        elif prim == "scan":
+            total += params["length"] * _dma_bytes_in(params["jaxpr"].jaxpr)
+        elif prim == "cond":
+            total += max(_dma_bytes_in(b.jaxpr) for b in params["branches"])
+        else:
+            for name in _CALL_PARAM_NAMES + ("body_jaxpr",):
+                if name in params:
+                    sub = params[name]
+                    total += _dma_bytes_in(getattr(sub, "jaxpr", sub))
     return total
 
 
@@ -187,10 +230,12 @@ def _pallas_cost(eqn) -> Tuple[float, float]:
     inside the kernel body is VMEM/register-resident, which is exactly the
     HW-path property the proxy exists to measure.
     """
-    body_f, _ = jaxpr_cost(eqn.params["jaxpr"])
+    body = eqn.params["jaxpr"]
+    body_f, _ = jaxpr_cost(body)
     grid = tuple(int(g) for g in eqn.params["grid_mapping"].grid)
     steps = float(np.prod(grid)) if grid else 1.0
-    return steps * body_f, _pallas_block_traffic(eqn)
+    dma = _dma_bytes_in(getattr(body, "jaxpr", body))
+    return steps * body_f, _pallas_block_traffic(eqn) + steps * dma
 
 
 def jaxpr_cost(jaxpr) -> Tuple[float, float]:

@@ -123,6 +123,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.decode_attention.decode_attention import pages_per_block
 from repro.serve import calibrate, sla, spec_decode
 from repro.serve.audit import audit_pool
 from repro.serve.faults import InjectedFault, KernelBackendError, poison_pages
@@ -159,7 +160,7 @@ TERMINAL_STATUSES = (STATUS_OK, STATUS_SHED, STATUS_TIMEOUT,
 COUNTERS = (
     "decode_steps",
     "decode_ctx_tokens",    # sum over steps of live rows' slot_pos + 1
-    "decode_grid_tokens",   # sum over steps of slots x attention bucket
+    "decode_grid_tokens",   # keys the attention kernel visits, over steps
     "prefill_tokens",       # uncached prompt tokens computed
     "prefill_causal_keys",  # sum of n * start + n (n + 1) / 2
     "admissions",           # requests whose first token a prefill sampled
@@ -1491,15 +1492,32 @@ class ServeEngine:
 
     def _count_decode(self, st: "_SchedState", window: int, grid: int):
         """Decode counters, where the step's shapes are chosen: the keys
-        each live row needs (``slot_pos + window``) and what the
-        attention grid covers, every slot over the ``grid``-token bucket.
-        ``decode_grid_tokens`` reads the grid as it is today: a change to
-        the kernel's grid changes this counter."""
+        each live row needs (``slot_pos + window``) and the keys the
+        attention kernel's grid visits at the ``grid``-token bucket
+        (:meth:`_grid_tokens`)."""
         c = st.counters
         c["decode_steps"] += 1
         c["decode_ctx_tokens"] += sum(st.slot_pos[s] + window
                                       for s in st.live)
-        c["decode_grid_tokens"] += self.slots * grid
+        c["decode_grid_tokens"] += self._grid_tokens(st, window, grid)
+
+    def _grid_tokens(self, st: "_SchedState", window: int, grid: int):
+        """Keys the attention kernel visits in one step.  Dense cache:
+        every slot over the bucket.  Paged pool: each row's keys rounded
+        up to the kernel's compute block of ``pages_per_block`` pages —
+        a live row ``slot_pos + window``, a prefilling row (parked at the
+        end of the sequence) the whole bucket, a free row nothing."""
+        if st.mgr is None:
+            return self.slots * grid
+        cfg = self.model.cfg
+        nb = cdiv(grid, self.page_size)
+        block = self.page_size * pages_per_block(
+            nb, self.page_size, window * (cfg.n_heads // cfg.n_kv_heads),
+            cfg.n_kv_heads, cfg.d_head, st.pool["k_pages"].dtype.itemsize)
+        keys = [st.slot_pos[s] + window for s in st.live]
+        keys += [grid] * len(st.prefilling)
+        return sum(_round_up(min(k, nb * self.page_size), block)
+                   for k in keys)
 
     def _count_prefill(self, st: "_SchedState", n: int, start: int = 0):
         """Prefill counters: ``n`` uncached prompt tokens computed after

@@ -20,10 +20,12 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro.configs.registry import reduced_config
+from repro.kernels.decode_attention.decode_attention import pages_per_block
 from repro.models.lm import Model
 from repro.serve import Request, ServeEngine
 from repro.serve.async_engine import AsyncServeEngine
 from repro.serve.engine import COUNTERS
+from repro.serve.kv_cache import TRASH_PAGE
 from repro.serve.trace import SPANS, column
 from repro.serve.workload import TimedRequest
 
@@ -137,6 +139,21 @@ def test_every_phase_is_spanned(traced):
     assert sum(ts["fetch_s"]) > 0 and sum(ts["commit_s"]) > 0
 
 
+def _kernel_keys(eng, pool, tables, pos, attend):
+    """Keys the paged decode kernel visits, from the step's own inputs:
+    rows whose table starts at the trash page read nothing, the others
+    ``pos + 1`` keys within the bucket's table, in compute blocks."""
+    cfg, page = eng.model.cfg, eng.page_size
+    nb = -(-attend // page)
+    block = page * pages_per_block(
+        nb, page, cfg.n_heads // cfg.n_kv_heads, cfg.n_kv_heads,
+        cfg.d_head, pool["k_pages"].dtype.itemsize)
+    tables, pos = np.asarray(tables)[:, :nb], np.asarray(pos)
+    keys = np.where(tables[:, 0] == TRASH_PAGE, 0,
+                    np.minimum(pos + 1, nb * page))
+    return int(sum(-(-int(k) // block) * block for k in keys))
+
+
 def test_counters_equal_what_the_steps_observe(model):
     seen = {"grid": 0, "ctx": 0, "steps": 0, "prefill": []}
 
@@ -145,7 +162,7 @@ def test_counters_equal_what_the_steps_observe(model):
 
         def paged_step(*a):
             seen["steps"] += 1
-            seen["grid"] += a[3].shape[0] * a[-1]   # slots x attend
+            seen["grid"] += _kernel_keys(eng, a[1], a[2], a[4], a[-1])
             seen["ctx"] += sum(st.slot_pos[s] + 1 for s in st.live)
             return step(*a)
 
