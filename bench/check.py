@@ -1,10 +1,13 @@
 """Decides ``correct``: what the timed window served, against the plain
-float32 reference (``reference.py``).
+float32 reference of the configuration's model family (its ``gaps``).
 
 Once the window has closed and the program's state is freed, a sample
 of the finished requests, drawn from the seed, is run through the
 reference: the longest request, then others in a seeded order until the
-sample holds ``MIN_TOKENS`` served tokens and ``MIN_REQUESTS`` requests.
+sample holds ``MIN_TOKENS`` served tokens and ``MIN_REQUESTS`` requests
+(or the configuration's ``check_requests``: a random-weight model may
+serve one token over and over, where no precision tells two forwards
+apart, so a configuration whose requests often do so samples more).
 Each sequence is the prompt followed by the served tokens, so the
 reference sees exactly the contexts the served path computed.  The
 numbers compared, each with its limit:
@@ -24,8 +27,6 @@ from typing import Dict, List
 
 import numpy as np
 
-from bench import reference
-
 MIN_TOKENS = 512
 MIN_REQUESTS = 3
 NO_READING = 1e30
@@ -35,7 +36,8 @@ def expected_tokens(rec, max_seq: int) -> int:
     return min(rec.max_new, max_seq - rec.n_prompt)
 
 
-def sample(finished: List, seed: int) -> List:
+def sample(finished: List, seed: int,
+           min_requests: int = MIN_REQUESTS) -> List:
     """The longest finished request, then others in a seeded order."""
     if not finished:
         return []
@@ -46,19 +48,20 @@ def sample(finished: List, seed: int) -> List:
     rest = [rest[i] for i in rng.permutation(len(rest))]
     out, served = [order[0]], len(order[0].tokens)
     for r in rest:
-        if served >= MIN_TOKENS and len(out) >= MIN_REQUESTS:
+        if served >= MIN_TOKENS and len(out) >= min_requests:
             break
         out.append(r)
         served += len(r.tokens)
     return out
 
 
-def compare(dims: Dict, seed: int, picked: List, *, limit: float,
+def compare(family, dims: Dict, seed: int, picked: List, *, limit: float,
             max_seq: int, control: bool = False) -> Dict[str, Dict]:
-    """The numbers compared, each ``{"value", "limit"}``."""
+    """The numbers compared, each ``{"value", "limit"}``; ``family`` is
+    the configuration's module under ``bench/families/``."""
     seqs = [(np.concatenate([r.prompt, np.asarray(r.tokens, np.int32)]),
              r.n_prompt) for r in picked if r.tokens]
-    sound, ctl = (reference.gaps(dims, seed, seqs, control=control)
+    sound, ctl = (family.gaps(dims, seed, seqs, control=control)
                   if seqs else ([], []))
     widest = _widest(ctl if control else sound)
     mismatch = sum(len(r.tokens) != expected_tokens(r, max_seq)

@@ -8,7 +8,8 @@ from bench.traffic.generate import percentile
 
 
 def read(run):
-    cut = run.work.started if run.work.started is not None else float("inf")
+    started = run.worklog.started
+    cut = started if started is not None else float("inf")
     waits = [r.admitted - r.due for r in run.counted
              if r.admitted is not None and r.due < cut]
     return percentile(waits, 95) if waits else None

@@ -15,7 +15,7 @@ def untraced_end(run) -> float:
     """Where host timings stop being read: the trace's start in a traced
     run (the profiler's Python tracer slows the host), else the window's
     close."""
-    return run.work.started if run.work.started is not None \
+    return run.worklog.started if run.worklog.started is not None \
         else run.window.close
 
 

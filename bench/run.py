@@ -3,14 +3,15 @@
   python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Finds the cell in ``BENCHMARK.json`` (its configuration under
-``bench/configs/``, its traffic mix under ``bench/traffic/``), makes the
-weights on the device from the seed, builds the serving engine, warms up
-every shape the mix can reach, then drives the engine open loop or
-closed loop for ``--seconds`` and reports the cell's end-to-end metrics
-(``--trace 0``) or its per-layer metrics read from a profiler trace of
-the window's last seconds (``--trace 1``).  After the window the
-program's state is freed and a sample of what it served is compared
-with the float32 reference (``check.py``).
+``bench/configs/``, the configuration's model family under
+``bench/families/``, its traffic mix under ``bench/traffic/``), makes
+the family's weights on the device from the seed, builds the serving
+engine, warms up every shape the mix can reach, then drives the engine
+open loop or closed loop for ``--seconds`` and reports the cell's
+end-to-end metrics (``--trace 0``) or its per-layer metrics read from a
+profiler trace of the window's last seconds (``--trace 1``).  After the
+window the program's state is freed and a sample of what it served is
+compared with the family's float32 reference (``check.py``).
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown``
@@ -20,8 +21,8 @@ or fewer chips than the cell asks for, it exits non-zero and prints no
 result.
 
 ``--control 1`` runs the same window but computes the comparison with
-the float8 control in the program's place (see ``reference.py``); it
-has to come out not correct.
+the float8 control in the program's place (see the family's ``gaps``
+under ``bench/families/``); it has to come out not correct.
 """
 
 from __future__ import annotations
@@ -132,8 +133,9 @@ class RunData:
     timeseries: Dict[str, Any]
     num_pages: int
     model: Dict[str, Any]
+    family: Any                 # the cell's module under bench/families/
     peak: Dict[str, Any]
-    work: Any
+    worklog: Any                # system.WorkLog
     trace: Any = None
 
 
@@ -171,12 +173,10 @@ def _serve(cell: spec.Cell, *, seed: int, seconds: float, trace: bool,
     only, so that nothing of the program outlives it."""
     import jax
 
-    from bench import weights
-
     dims, conf, traffic = cell.model, cell.config, cell.traffic
     counter = CompileCounter()
-    params = weights.make_params(dims, seed)
-    engine = system.build_engine(conf, dims, params)
+    params = cell.family.make_params(dims, seed)
+    engine = system.build_engine(cell.family, conf, dims, params)
     n_warm = system.warmup(engine, traffic, dims["vocab"])
     log(f"warm-up: {n_warm} requests over "
         f"{len(system.warmup_lengths(engine, traffic))} decode buckets")
@@ -223,7 +223,8 @@ def _serve(cell: spec.Cell, *, seed: int, seconds: float, trace: bool,
     data = RunData(loop=traffic["loop"], setup_s=win.open - t_start,
                    window=win, records=records, counted=counted,
                    timeseries=ts, num_pages=engine.num_pages, model=dims,
-                   peak={}, work=work, trace=reduced)
+                   family=cell.family, peak={}, worklog=work,
+                   trace=reduced)
     late = sorted(win.lateness) or [0.0]
     log(f"window: {seconds} s, {len(records)} requests submitted, "
         f"{len(counted)} counted; generator late by p50 "
@@ -293,11 +294,13 @@ def run_cell(cell: spec.Cell, *, seed: int, seconds: float, trace: bool,
     log(f"counted requests by status: {statuses}")
 
     _free(devices)
-    picked = check.sample(finished, seed)
+    picked = check.sample(finished, seed, cell.config.get(
+        "check_requests", check.MIN_REQUESTS))
     with jax.default_device(devices[0]):
         checked = check.compare(
-            cell.model, seed, picked, limit=cell.config["logit_gap_limit"],
-            max_seq=max_seq, control=control)
+            cell.family, cell.model, seed, picked,
+            limit=cell.config["logit_gap_limit"], max_seq=max_seq,
+            control=control)
     log(f"checked {len(picked)} requests (uids "
         f"{[r.uid for r in picked]}) against the float32 reference"
         + (" with the float8 control in the program's place"

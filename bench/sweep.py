@@ -93,12 +93,10 @@ def main(argv=None):
     devices = run.accelerator(cell.chips)
     import jax
 
-    from bench import weights
-
-    dims = cell.model
+    dims, family = cell.model, cell.family
     with jax.default_device(devices[0]):
-        engine = system.build_engine(cell.config, dims,
-                                     weights.make_params(dims, args.seed))
+        engine = system.build_engine(family, cell.config, dims,
+                                     family.make_params(dims, args.seed))
         system.warmup(engine, cell.traffic, dims["vocab"])
         rows, knee = [], None
         for rate in sorted(float(x) for x in args.rates.split(",")):

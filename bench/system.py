@@ -1,17 +1,18 @@
 """The system under test, driven as a user drives it.
 
-This is the one file of the benchmark that imports the program: it
-builds ``repro.serve.engine.ServeEngine`` (paged layout) over the
-benchmark's weights, warms up the shapes a traffic mix will use, and
-drives the timed window through ``repro.serve.async_engine.
+This file, and each family's ``program_config``, are all of the
+benchmark that imports the program: it builds
+``repro.serve.engine.ServeEngine`` (paged layout) over the benchmark's
+weights and the family's model, warms up the shapes a traffic mix will
+use, and drives the timed window through ``repro.serve.async_engine.
 AsyncServeEngine`` on the wall clock.  It records, per request, when it
 was due, when each token reached the client and what the engine's
 ledger says (admission time, cached prefix tokens), and the engine's
 per-round time series.
 
 In a traced run it also wraps the engine's jitted steps to note the
-lengths each step actually computed (for the work functions in
-``work.py``) and names the host's scheduler phases with profiler
+lengths each step actually computed (for the family's work
+functions) and names the host's scheduler phases with profiler
 annotations (for the idle-gap breakdown); nothing of this runs
 otherwise.
 """
@@ -26,29 +27,17 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 
-def program_config(conf: dict, dims: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.models.config import ModelConfig
-
-    return ModelConfig(
-        name=conf["name"], family="dense", n_layers=dims["n_layers"],
-        d_model=dims["d_model"], n_heads=dims["n_heads"],
-        n_kv_heads=dims["n_kv_heads"], d_head=dims["d_head"],
-        d_ff=dims["d_ff"], vocab=dims["vocab"], qkv_bias=True,
-        rope_theta=dims["rope_theta"], norm_eps=dims["norm_eps"],
-        tie_embeddings=dims["tie_embeddings"])
-
-
-def build_engine(conf: dict, dims: dict, params):
-    """``ServeEngine`` with the configuration's engine settings, serving
-    in bfloat16 as the published config states."""
+def build_engine(family, conf: dict, dims: dict, params):
+    """``ServeEngine`` with the configuration's engine settings over the
+    family's model, serving in bfloat16 as the published config
+    states."""
     import jax.numpy as jnp
 
     from repro.models.lm import Model
     from repro.serve.engine import ServeEngine
 
-    model = Model(program_config(conf, dims), param_dtype=jnp.bfloat16,
-                  compute_dtype=jnp.bfloat16)
+    model = Model(family.program_config(conf, dims),
+                  param_dtype=jnp.bfloat16, compute_dtype=jnp.bfloat16)
     return ServeEngine(model, params, **conf["engine"])
 
 

@@ -26,8 +26,9 @@ SEED = 2 ** 31 + 77
 
 def tiny_cell(traffic: str) -> spec.Cell:
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    return spec.Cell(name="tiny", chips=1,
-                     config=json.loads((HERE / "tiny.json").read_text()),
+    conf = json.loads((HERE / "tiny.json").read_text())
+    return spec.Cell(name="tiny", chips=1, config=conf,
+                     family=spec.family(conf),
                      traffic=json.loads((HERE / f"{traffic}.json")
                                         .read_text()),
                      end_to_end=bench["end_to_end"],
@@ -90,3 +91,13 @@ def test_control_is_not_correct():
 def test_fault_in_the_timed_path_is_not_correct(fault):
     res = _run(fault=fault)
     assert not res["correct"], res["checked"]
+
+
+def test_sample_takes_the_longest_then_the_configured_requests():
+    from types import SimpleNamespace as R
+    done = [R(uid=u, n_prompt=10 * u, tokens=[1] * 200) for u in range(12)]
+    three = check.sample(done, SEED)
+    eight = check.sample(done, SEED, 8)
+    assert three[0].uid == 11 and eight[0].uid == 11
+    assert len(three) == 3 and len(eight) == 8
+    assert [r.uid for r in eight[:3]] == [r.uid for r in three]

@@ -9,8 +9,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import reference, weights
-from bench.system import program_config
+from bench import weights
+from bench.families import dense_gqa
+from bench.families.dense_gqa import program_config
 
 DIMS = {"n_layers": 2, "d_model": 128, "n_heads": 4, "n_kv_heads": 2,
         "d_head": 32, "d_ff": 256, "vocab": 512, "tie_embeddings": True,
@@ -27,10 +28,10 @@ def test_prefill_and_paged_decode_match_program(tie):
     cfg = program_config({"name": "t"}, dims)
     model = Model(cfg, param_dtype=jnp.float32, compute_dtype=jnp.float32,
                   attn_backend="jnp", decode_backend="jnp")
-    params = weights.make_params(dims, seed, jnp.float32)
+    params = dense_gqa.make_params(dims, seed, jnp.float32)
     rng = np.random.default_rng(0)
     toks = rng.integers(0, dims["vocab"], n + steps).astype(np.int32)
-    want = reference.logits(dims, seed, toks)
+    want = dense_gqa.logits(dims, seed, toks)
 
     with jax.default_matmul_precision("highest"):
         batch = {"tokens": jnp.asarray(toks[None, :n])}
@@ -57,17 +58,17 @@ def test_prefill_and_paged_decode_match_program(tie):
 
 
 def test_weights_are_exact_in_bf16():
-    p32 = weights.make_params(DIMS, 5, jnp.float32)
-    p16 = weights.make_params(DIMS, 5, jnp.bfloat16)
+    p32 = dense_gqa.make_params(DIMS, 5, jnp.float32)
+    p16 = dense_gqa.make_params(DIMS, 5, jnp.bfloat16)
     for a, b in zip(jax.tree.leaves(p32), jax.tree.leaves(p16)):
         np.testing.assert_array_equal(np.asarray(a),
                                       np.asarray(b.astype(jnp.float32)))
 
 
 def test_stacked_layers_equal_one_layer_at_a_time():
-    p = weights.make_params(DIMS, 2 ** 40 + 9, jnp.float32)
+    p = dense_gqa.make_params(DIMS, 2 ** 40 + 9, jnp.float32)
     key = weights.base_key(2 ** 40 + 9)
-    one = weights.layer_leaves(key, DIMS, np.uint32(1), jnp.float32)
+    one = dense_gqa.layer_leaves(key, DIMS, np.uint32(1), jnp.float32)
     for a, b in zip(jax.tree.leaves(one),
                     jax.tree.leaves(jax.tree.map(lambda x: x[1],
                                                  p["layers"]))):
@@ -75,8 +76,8 @@ def test_stacked_layers_equal_one_layer_at_a_time():
 
 
 def test_seeds_past_32_bits_differ():
-    a = weights.make_params(DIMS, 7, jnp.float32)["embed"]
-    b = weights.make_params(DIMS, 7 + 2 ** 32, jnp.float32)["embed"]
+    a = dense_gqa.make_params(DIMS, 7, jnp.float32)["embed"]
+    b = dense_gqa.make_params(DIMS, 7 + 2 ** 32, jnp.float32)["embed"]
     assert not np.array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -86,10 +87,10 @@ def test_control_lies_further_from_the_reference():
     rng = np.random.default_rng(1)
     toks = list(rng.integers(0, DIMS["vocab"], 60))
     for _ in range(30):
-        toks.append(int(np.argmax(reference.logits(DIMS, 4, toks)[-1])))
+        toks.append(int(np.argmax(dense_gqa.logits(DIMS, 4, toks)[-1])))
     seqs = [(np.asarray(toks, np.int32), 60)]
-    sound = reference.gaps(DIMS, 4, seqs)[0][0]
-    again, ctl = reference.gaps(DIMS, 4, seqs, control=True)
+    sound = dense_gqa.gaps(DIMS, 4, seqs)[0][0]
+    again, ctl = dense_gqa.gaps(DIMS, 4, seqs, control=True)
     ctl = ctl[0]
     np.testing.assert_array_equal(sound, again[0])
     assert sound.shape == ctl.shape == (30,)
@@ -104,10 +105,10 @@ def test_blocked_head_matches_whole_head(tie, monkeypatch):
     dims = dict(DIMS, tie_embeddings=tie)
     rng = np.random.default_rng(2)
     seqs = [(rng.integers(0, DIMS["vocab"], 70).astype(np.int32), 50)]
-    whole, whole_ctl = reference.gaps(dims, 6, seqs, control=True)
-    monkeypatch.setattr(reference, "VOCAB_BLOCK", 200)
-    reference._head_gaps.clear_cache()
-    part, part_ctl = reference.gaps(dims, 6, seqs, control=True)
-    reference._head_gaps.clear_cache()
+    whole, whole_ctl = dense_gqa.gaps(dims, 6, seqs, control=True)
+    monkeypatch.setattr(dense_gqa, "VOCAB_BLOCK", 200)
+    dense_gqa._head_gaps.clear_cache()
+    part, part_ctl = dense_gqa.gaps(dims, 6, seqs, control=True)
+    dense_gqa._head_gaps.clear_cache()
     np.testing.assert_allclose(part[0], whole[0], atol=1e-5)
     np.testing.assert_allclose(part_ctl[0], whole_ctl[0], atol=1e-5)

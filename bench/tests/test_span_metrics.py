@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from bench import run, spec, span_reduce, spans
+from bench.families import dense_gqa
 from bench.tests.test_harness import PEAK, SEED, tiny_cell
 from bench.tests.test_trace_reduce import HOST, ev, small_trace
 from bench.trace_reduce import reduce
@@ -100,8 +101,8 @@ def fake_run(series=SERIES, ops=FLASH_OPS):
     return SimpleNamespace(
         timeseries={k: np.asarray(v, float) for k, v in series.items()},
         window=SimpleNamespace(open=2.0, close=6.0),
-        work=SimpleNamespace(started=5.0),
-        trace=SimpleNamespace(ops=ops), model=DIMS,
+        worklog=SimpleNamespace(started=5.0),
+        trace=SimpleNamespace(ops=ops), model=DIMS, family=dense_gqa,
         peak={"bf16_flops_per_s": 1e9, "hbm_bytes_per_s": 1e8})
 
 

@@ -6,7 +6,7 @@ import types
 import jax
 import pytest
 
-from bench import spec, sweep, system, weights
+from bench import spec, sweep, system
 from bench.tests.test_harness import tiny_cell
 
 
@@ -25,8 +25,8 @@ def test_one_rate_reads_queue_and_tails():
     cell = tiny_cell("tiny-open")
     dims = cell.model
     with jax.default_device(jax.devices()[0]):
-        engine = system.build_engine(cell.config, dims,
-                                     weights.make_params(dims, 5))
+        engine = system.build_engine(cell.family, cell.config, dims,
+                                     cell.family.make_params(dims, 5))
         system.warmup(engine, cell.traffic, dims["vocab"])
         row = sweep.one_rate(engine, cell.traffic, 4.0, seed=5,
                              seconds=2.0, vocab=dims["vocab"])
@@ -43,6 +43,6 @@ def test_queue_wait_counts_requests_due_before_the_trace(started, expected):
     reqs = [types.SimpleNamespace(due=float(d), admitted=d + w)
             for d, w in ((10, 0.5), (12, 0.5), (20, 5.0))]
     run = types.SimpleNamespace(counted=reqs,
-                                work=system.WorkLog(started=started))
+                                worklog=system.WorkLog(started=started))
     assert spec.metric_reader("queue_wait_p95_s")(run) == pytest.approx(
         expected, abs=0.5 if started is None else 1e-9)

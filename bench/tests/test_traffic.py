@@ -1,5 +1,6 @@
 """The traffic generator: same seed, same requests; other seeds, the
-same set of sizes and gaps in each segment in another order."""
+same set of sizes and gaps in each segment in another order, or in the
+same order where the mix fixes its ``schedule_seed``."""
 
 import json
 from pathlib import Path
@@ -38,12 +39,19 @@ def test_open_loop_window_holds_same_work_for_every_seed():
         return (sorted(len(r.prompt) for r in w),
                 sorted(r.max_new for r in w))
 
-    a, b = _open(spec, 1), _open(spec, 2)
+    shuffled = {k: v for k, v in spec.items() if k != "schedule_seed"}
+    a, b = _open(shuffled, 1), _open(shuffled, 2)
     assert window(a) == window(b)
     assert [len(r.prompt) for r in a] != [len(r.prompt) for r in b]
     rate = spec["arrivals"]["rate_per_s"]
     assert len([r for r in a if ramp <= r.due_s < ramp + secs]) == round(
         rate * secs)
+    # with schedule_seed every seed replays one schedule; tokens differ
+    a, b = _open(spec, 1), _open(spec, 2)
+    assert "schedule_seed" in spec
+    assert [(len(r.prompt), r.max_new, r.due_s) for r in a] == [
+        (len(r.prompt), r.max_new, r.due_s) for r in b]
+    assert not np.array_equal(a[0].prompt, b[0].prompt)
 
 
 def test_open_loop_lengths_follow_the_mix():

@@ -9,7 +9,12 @@ are drawn stratified: each segment of the run (the ramp before the
 window, the window, the tail after it) holds the same set of values for
 every seed, at the quantiles ``(i + 0.5) / n`` of its distribution, and
 the seed chooses their order and the token ids.  So runs with different
-seeds do the same work, and their spread is the system's.
+seeds do the same work, and their spread is the system's.  A mix that
+gives ``"schedule_seed"`` draws that order from it instead: every run
+replays one schedule of sizes and due times, and ``--seed`` chooses the
+token ids alone (an open-loop tail that rests on which long prompts
+arrive back to back would otherwise read the order more than the
+system).
 
 Open loop (``"loop": "open"``): requests are due at fixed times from the
 start of arrivals; the window opens ``ramp_s`` later.  Closed loop
@@ -105,13 +110,16 @@ def _arrivals(spec: Dict, n: int, span: float, rng) -> np.ndarray:
 
 
 def _prompts(spec: Dict, n: int, vocab: int, rng,
-             systems: Optional[List[np.ndarray]]):
-    """Prompt token arrays (and tenants) for ``n`` requests."""
-    lens = lognormal_lengths(n, rng, **spec["prompt"])
+             systems: Optional[List[np.ndarray]], order=None):
+    """Prompt token arrays (and tenants) for ``n`` requests; lengths and
+    tenants in an order drawn from ``order`` (default ``rng``), token ids
+    from ``rng``."""
+    order = rng if order is None else order
+    lens = lognormal_lengths(n, order, **spec["prompt"])
     tenants = np.full(n, -1)
     if systems is not None:
         t = spec["tenants"]
-        tenants = rng.permutation(np.repeat(
+        tenants = order.permutation(np.repeat(
             np.arange(t["count"]), zipf_counts(n, t["count"], t["zipf_s"])))
     out = []
     for i in range(n):
@@ -125,6 +133,8 @@ def open_loop(spec: Dict, seed: int, seconds: float,
               vocab: int) -> List[Req]:
     """Requests due over ramp, window and tail, in arrival order."""
     rng = np.random.default_rng(seed)
+    order = (np.random.default_rng(spec["schedule_seed"])
+             if "schedule_seed" in spec else rng)
     systems = None
     if spec.get("tenants"):
         t = spec["tenants"]
@@ -136,9 +146,9 @@ def open_loop(spec: Dict, seed: int, seconds: float,
     out: List[Req] = []
     for start, span in segments:
         n = max(1, int(round(rate * span)))
-        due = start + _arrivals(spec, n, span, rng)
-        prompts, tenants = _prompts(spec, n, vocab, rng, systems)
-        outs = lognormal_lengths(n, rng, **spec["output"])
+        due = start + _arrivals(spec, n, span, order)
+        prompts, tenants = _prompts(spec, n, vocab, rng, systems, order)
+        outs = lognormal_lengths(n, order, **spec["output"])
         for i in range(n):
             out.append(Req(uid=len(out), prompt=prompts[i],
                            max_new=int(outs[i]), due_s=float(due[i]),
