@@ -17,11 +17,12 @@ from repro.configs.whisper_small import CONFIG as _whisper
 from repro.configs.rwkv6_7b import CONFIG as _rwkv6
 from repro.configs.internvl2_1b import CONFIG as _internvl2
 from repro.configs.zamba2_2_7b import CONFIG as _zamba2
+from repro.configs.qwen2_57b_a14b import CONFIG as _qwen2_57b
 
 ARCHS: Dict[str, ModelConfig] = {
     c.name: c
     for c in (_olmoe, _granite, _qwen110b, _minicpm3, _qwen2_15b, _qwen32b,
-              _whisper, _rwkv6, _internvl2, _zamba2)
+              _whisper, _rwkv6, _internvl2, _zamba2, _qwen2_57b)
 }
 
 
@@ -59,6 +60,11 @@ def reduced_config(name: str) -> ModelConfig:
     if c.family == "moe":
         changes["n_experts"] = 8
         changes["top_k"] = min(c.top_k, 4)
+        if c.shared_expert_ff:
+            # keep the shared expert, and hold a strict subset of the
+            # routed experts, so smoke tests run a chip's share of a layer
+            changes.update(shared_expert_ff=256, experts_held=4,
+                           expert_offset=2)
     if c.attn_type == "mla":
         changes.update(q_lora_rank=64, kv_lora_rank=32, qk_nope_head_dim=32,
                        qk_rope_head_dim=16, v_head_dim=32)

@@ -30,11 +30,16 @@ class ModelConfig:
     v_head_dim: int = 64
 
     # MoE
-    n_experts: int = 0
+    n_experts: int = 0             # the router's width (published count)
     top_k: int = 0
-    capacity_factor: float = 1.25
-    infer_capacity_factor: float = 2.0   # prefill/decode capacity (no-drop
-                                         # margin without training's budget)
+    # experts this chip holds: [expert_offset, expert_offset +
+    # experts_held); 0 holds all n_experts (expert parallelism gives each
+    # chip its share of every layer's experts)
+    expert_offset: int = 0
+    experts_held: int = 0
+    shared_expert_ff: int = 0      # >0: a gated shared SwiGLU expert
+    norm_topk_prob: bool = True    # renormalise the top-k router weights
+    capacity_factor: float = 1.25  # training's dispatch; serving drops none
 
     # SSM / recurrent
     rwkv_head_size: int = 64
@@ -75,6 +80,11 @@ class ModelConfig:
             object.__setattr__(self, "d_head", self.d_model // max(self.n_heads, 1))
 
     @property
+    def n_held(self) -> int:
+        """Routed experts this chip holds."""
+        return self.experts_held or self.n_experts
+
+    @property
     def vocab_padded(self) -> int:
         if self.pad_vocab_to <= 0:
             return self.vocab
@@ -99,7 +109,8 @@ class ModelConfig:
             attn = L * (d * self.n_heads * self.d_head
                         + 2 * d * self.n_kv_heads * self.d_head
                         + self.n_heads * self.d_head * d)
-            ffn = L * self.n_experts * 3 * d * self.d_ff + L * d * self.n_experts
+            ffn = L * (self.n_held * 3 * d * self.d_ff + d * self.n_experts
+                       + self._shared_params)
             return emb + attn + ffn
         if self.family == "ssm":  # rwkv6
             tm = L * d * d * 5          # r,k,v,g,o projections
@@ -130,8 +141,14 @@ class ModelConfig:
         attn = L * (d * self.n_heads * self.d_head
                     + 2 * d * self.n_kv_heads * self.d_head
                     + self.n_heads * self.d_head * d)
-        ffn = L * self.top_k * 3 * d * self.d_ff
+        ffn = L * (self.top_k * 3 * d * self.d_ff + self._shared_params)
         return emb + attn + ffn
+
+    @property
+    def _shared_params(self) -> int:
+        """The shared expert and its gate, per layer."""
+        fs = self.shared_expert_ff
+        return 3 * self.d_model * fs + self.d_model if fs else 0
 
 
 @dataclasses.dataclass(frozen=True)

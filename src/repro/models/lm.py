@@ -40,7 +40,7 @@ from repro.models.layers import (
     rmsnorm,
     swiglu,
 )
-from repro.models.moe import init_moe_params, moe_block
+from repro.models.moe import held_experts, init_moe_params, moe_block
 from repro.models.recurrent import (
     init_mamba2_params,
     init_rwkv6_params,
@@ -510,8 +510,9 @@ class Model:
         """
         cfg = self.cfg
         if "k_pages" in cache:
-            x = self._embed(params, tokens[:, None])
-            return self._gqa_decode_paged(params, cache, x, pos, attend_len)
+            logits, cache, _ = self._gqa_decode_paged(params, cache, tokens,
+                                                      pos, attend_len)
+            return logits, cache
         x = self._embed(params, tokens[:, None])
 
         if cfg.family == "ssm":
@@ -565,9 +566,8 @@ class Model:
                                     (c["cross_k"], c["cross_v"]), cfg)
             g = rmsnorm(h, p["ln2"], cfg.norm_eps, self.wf)
             if cfg.family == "moe":
-                h = h + moe_block(
-                    p["moe"], g, cfg,
-                    capacity_factor=max(cfg.infer_capacity_factor, 8.0))
+                h = h + held_experts(p["moe"], g, cfg,
+                                     backend=self.decode_backend)[0]
             else:
                 h = h + swiglu(g, p["mlp"]["w_gate"], p["mlp"]["w_up"],
                                p["mlp"]["w_down"])
@@ -580,7 +580,8 @@ class Model:
                                                cache, unroll)
         return self._head(params, x)[:, 0, :cfg.vocab], new_cache
 
-    def _gqa_decode_layers(self, params, x, positions, write_attend):
+    def _gqa_decode_layers(self, params, x, positions, write_attend,
+                           valid=None):
         """Shared unrolled decode/verify layer body for the GQA families.
 
         x: (B, S, d) embedded tokens sitting at absolute ``positions``
@@ -591,6 +592,9 @@ class Model:
         indirection, one query row vs a causally-masked window).  Keeping
         one loop keeps the dense, paged, and verify paths bit-identical by
         construction — a change to the layer math cannot diverge them.
+        ``valid`` (B, S) marks the rows the expert layer routes (None:
+        all).  Returns (x, stats): the expert layers' counts summed over
+        layers, None for a model without them.
         """
         from repro.models.attention import gqa_qkv
         from repro.models.layers import rope_freqs
@@ -598,6 +602,7 @@ class Model:
         cfg = self.cfg
         b, s, _ = x.shape
         rope = rope_freqs(cfg.d_head, cfg.rope_theta, positions)
+        stats = None
         for l in range(self._n_scan_layers):
             p = jax.tree.map(lambda a: a[l], params["layers"])
             g = rmsnorm(x, p["ln1"], cfg.norm_eps, self.wf)
@@ -607,17 +612,21 @@ class Model:
                                p["attn"]["wo"].astype(x.dtype))
             g = rmsnorm(x, p["ln2"], cfg.norm_eps, self.wf)
             if cfg.family == "moe":
-                x = x + moe_block(
-                    p["moe"], g, cfg,
-                    capacity_factor=max(cfg.infer_capacity_factor, 8.0))
+                # the stacked experts, read at layer l where they live
+                y, st = held_experts(params["layers"]["moe"], g, cfg,
+                                     layer=l, valid=valid,
+                                     backend=self.decode_backend)
+                x = x + y
+                stats = st if stats is None else stats + st
             else:
                 x = x + swiglu(g, p["mlp"]["w_gate"], p["mlp"]["w_up"],
                                p["mlp"]["w_down"])
-        return x
+        return x, stats
 
-    def _gqa_decode_loop(self, params, x, pos, write_attend):
-        x = self._gqa_decode_layers(params, x, pos[:, None], write_attend)
-        return self._head(params, x)[:, 0, :self.cfg.vocab]
+    def _gqa_decode_loop(self, params, x, pos, write_attend, valid=None):
+        x, stats = self._gqa_decode_layers(params, x, pos[:, None],
+                                           write_attend, valid)
+        return self._head(params, x)[:, 0, :self.cfg.vocab], stats
 
     def _gqa_decode_unrolled(self, params, cache, x, pos,
                              attend_len: Optional[int]):
@@ -642,12 +651,12 @@ class Model:
                                     attend_len=attend_len,
                                     backend=self.decode_backend)
 
-        logits = self._gqa_decode_loop(params, x, pos, write_attend)
+        logits, _ = self._gqa_decode_loop(params, x, pos, write_attend)
         return logits, {"k": ck, "v": cv}
 
-    def _gqa_decode_paged(self, params, cache, x, pos,
+    def _gqa_decode_paged(self, params, cache, tokens, pos,
                           attend_len: Optional[int]):
-        """Zero-copy decode through the paged block pool.
+        """Zero-copy decode of ``tokens`` (B,) through the paged block pool.
 
         Per layer the fresh K/V row lands at ``(page, offset)`` resolved
         through the slot's block table — a scatter at a *table-dependent*
@@ -661,11 +670,16 @@ class Model:
         hand the scales to the attention gather for fused dequant.  The
         per-row scale makes the stored bytes a pure function of the row's
         values, so incremental writes and recompute/swap replay produce
-        identical pages.
+        identical pages.  A free slot (table row at the trash page) routes
+        nothing through the expert layer.  Returns (logits, cache, stats):
+        the expert layers' counts summed over layers
+        (``repro.models.moe.MOE_STATS``), None for a model without them;
+        the serving engine's paged step reads them here.
         """
         from repro.models.attention import paged_decode_attention
-        from repro.serve.kv_cache import quantize_kv_rows
+        from repro.serve.kv_cache import TRASH_PAGE, quantize_kv_rows
 
+        x = self._embed(params, tokens[:, None])
         kp, vp, bt = cache["k_pages"], cache["v_pages"], cache["block_tables"]
         quantized = "k_scales" in cache
         ks = cache.get("k_scales")
@@ -692,11 +706,13 @@ class Model:
                                           k_scales=ks, v_scales=vs,
                                           backend=self.decode_backend)
 
-        logits = self._gqa_decode_loop(params, x, pos, write_attend)
+        logits, stats = self._gqa_decode_loop(
+            params, x, pos, write_attend,
+            valid=(bt[:, 0] != TRASH_PAGE)[:, None])
         out = {"k_pages": kp, "v_pages": vp, "block_tables": bt}
         if quantized:
             out["k_scales"], out["v_scales"] = ks, vs
-        return logits, out
+        return logits, out, stats
 
     # ------------------------------------------------------ speculative verify
     def decode_verify_step(self, params, cache, tokens: jnp.ndarray,
@@ -725,19 +741,23 @@ class Model:
                              "(k_pages/v_pages/block_tables); got leaves "
                              f"{sorted(cache)}")
         x = self._embed(params, tokens)
-        return self._gqa_verify_paged(params, cache, x, pos, attend_len,
-                                      verify_backend)
+        x, cache, _ = self._paged_window(params, cache, x, pos, attend_len,
+                                         verify_backend)
+        logits = self._head(params, x)[..., :self.cfg.vocab]   # (B, T, V)
+        return logits, cache
 
     def _paged_window(self, params, cache, x, pos,
                       attend_len: Optional[int],
-                      verify_backend: Optional[str]):
+                      verify_backend: Optional[str], valid=None):
         """Shared T-token window body over the paged cache: per layer the
         T fresh K/V rows scatter at table-resolved ``(page, offset)``
         pairs, then the verify attention masks each query row at its own
         position.  Backs both the speculative verify
         (:meth:`decode_verify_step`) and the shared-prefix suffix prefill
         (:meth:`prefill_suffix`) — one body keeps their math identical.
-        Returns (hidden (B, T, d), new cache)."""
+        Rows written to the trash page, and rows outside ``valid`` (B, T)
+        where given, route nothing through the expert layer.  Returns
+        (hidden (B, T, d), new cache, stats)."""
         from repro.models.attention import paged_verify_attention
 
         from repro.serve.kv_cache import TRASH_PAGE, quantize_kv_rows
@@ -778,19 +798,15 @@ class Model:
                                           k_scales=ks, v_scales=vs,
                                           backend=backend)
 
-        x = self._gqa_decode_layers(params, x, positions, write_attend)
+        routed = page != TRASH_PAGE
+        if valid is not None:
+            routed = routed & valid
+        x, stats = self._gqa_decode_layers(params, x, positions,
+                                           write_attend, routed)
         out = {"k_pages": kp, "v_pages": vp, "block_tables": bt}
         if quantized:
             out["k_scales"], out["v_scales"] = ks, vs
-        return x, out
-
-    def _gqa_verify_paged(self, params, cache, x, pos,
-                          attend_len: Optional[int],
-                          verify_backend: Optional[str]):
-        x, cache = self._paged_window(params, cache, x, pos, attend_len,
-                                      verify_backend)
-        logits = self._head(params, x)[..., :self.cfg.vocab]   # (B, T, V)
-        return logits, cache
+        return x, out, stats
 
     # -------------------------------------------------- shared-prefix prefill
     def prefill_suffix(self, params, cache, tokens: jnp.ndarray,
@@ -806,7 +822,8 @@ class Model:
         absolute positions start_pos[b] .. start_pos[b] + last_idx[b],
         with ``last_idx[b]`` the index of the row's last real token
         inside the window.  Returns (logits (B, V) at each row's last
-        real token, cache).
+        real token, cache, stats): the expert layers' counts summed over
+        layers (``repro.models.moe.MOE_STATS``), None without them.
 
         This is the verify window re-aimed at admission: every suffix
         K/V row is written through the block tables (shared prefix pages
@@ -816,19 +833,22 @@ class Model:
         for ``T`` suffix tokens is spent instead of the full prompt.
         Padding rows past ``last_idx`` write into the slot's private tail
         page (masked and progressively overwritten by decode, exactly
-        like right-padded dense prefill) or the trash page."""
+        like right-padded dense prefill) or the trash page, and route
+        nothing through the expert layer."""
         if "k_pages" not in cache:
             raise ValueError("prefill_suffix needs a paged cache "
                              "(k_pages/v_pages/block_tables); got leaves "
                              f"{sorted(cache)}")
         x = self._embed(params, tokens)
-        x, cache = self._paged_window(params, cache, x, start_pos,
-                                      attend_len, verify_backend)
+        real = jnp.arange(tokens.shape[1])[None, :] <= last_idx[:, None]
+        x, cache, stats = self._paged_window(params, cache, x, start_pos,
+                                             attend_len, verify_backend,
+                                             real)
         idx = jnp.broadcast_to(last_idx[:, None, None],
                                (x.shape[0], 1, x.shape[2]))
         last = jnp.take_along_axis(x, idx, axis=1)             # (B, 1, d)
         logits = self._head(params, last)[:, 0, :self.cfg.vocab]
-        return logits, cache
+        return logits, cache, stats
 
     # --------------------------------------------------------------- prefill
     def prefill(self, params, batch: Dict[str, jnp.ndarray], max_seq: int,
@@ -843,8 +863,16 @@ class Model:
         With right-padded prompt batches (bucketed admission) the causal
         mask makes position ``last_pos[b]`` independent of the padding, so
         the returned logits are exact; the padded tail of the cache is
-        masked out (and progressively overwritten) by the decode steps.
+        masked out (and progressively overwritten) by the decode steps,
+        and routes nothing through the expert layer.
         """
+        return self._prefill(params, batch, max_seq, last_pos)[:2]
+
+    def _prefill(self, params, batch, max_seq, last_pos):
+        """:meth:`prefill` with a third output, the expert layers' counts
+        summed over layers (``repro.models.moe.MOE_STATS``), None for a
+        model without them; the serving engine's paged admission reads
+        them here."""
         cfg = self.cfg
         tokens = batch["tokens"]
         x = self._embed(params, tokens)
@@ -870,7 +898,8 @@ class Model:
                 return h, st
 
             x, cache = jax.lax.scan(body, x, params["layers"])
-            return self._head(params, last_hidden(x))[:, 0, :cfg.vocab], cache
+            return (self._head(params, last_hidden(x))[:, 0, :cfg.vocab],
+                    cache, None)
 
         if cfg.family == "hybrid":
             k = cfg.hybrid_attn_every
@@ -906,7 +935,8 @@ class Model:
                 "attn_k": ks,
                 "attn_v": vs,
             }
-            return self._head(params, last_hidden(x))[:, 0, :cfg.vocab], cache
+            return (self._head(params, last_hidden(x))[:, 0, :cfg.vocab],
+                    cache, None)
 
         if cfg.attn_type == "mla":
             def body(h, p):
@@ -922,9 +952,14 @@ class Model:
 
             x, (lats, ropes) = jax.lax.scan(body, x, params["layers"])
             cache = {"latent": lats, "rope": ropes}
-            return self._head(params, last_hidden(x))[:, 0, :cfg.vocab], cache
+            return (self._head(params, last_hidden(x))[:, 0, :cfg.vocab],
+                    cache, None)
 
         # gqa family (dense / moe / encdec / vlm)
+        routed = None
+        if cfg.family == "moe" and last_pos is not None:
+            routed = (jnp.arange(tokens.shape[1])[None, :]
+                      <= last_pos[:, None])
         enc = None
         if cfg.family == "encdec":
             enc = self._scan_encoder(
@@ -935,7 +970,8 @@ class Model:
                             params["vit_proj"].astype(fe.dtype))
             x = jnp.concatenate([fe, x], axis=1)
 
-        def body(h, p):
+        def body(h, inp):
+            p, l = inp
             g = rmsnorm(h, p["ln1"], cfg.norm_eps, self.wf)
             att, (kk, vv) = gqa_block_kv(p["attn"], g, cfg, causal=True,
                                          chunk_q=self.chunk_q,
@@ -950,19 +986,26 @@ class Model:
                 ys += [ck, cv]
             g = rmsnorm(h, p["ln2"], cfg.norm_eps, self.wf)
             if cfg.family == "moe":
-                # inference capacity (training keeps cfg.capacity_factor)
-                h = h + moe_block(p["moe"], g, cfg,
-                                  capacity_factor=cfg.infer_capacity_factor)
+                # the stacked experts, read at layer l where they live
+                y, st = held_experts(params["layers"]["moe"], g, cfg,
+                                     layer=l, valid=routed,
+                                     backend=self.decode_backend)
+                h = h + y
+                ys.append(st)
             else:
                 h = h + swiglu(g, p["mlp"]["w_gate"], p["mlp"]["w_up"],
                                p["mlp"]["w_down"])
             return h, tuple(ys)
 
-        x, ys = jax.lax.scan(body, x, params["layers"])
+        layer_ids = (jnp.arange(self._n_scan_layers) if cfg.family == "moe"
+                     else None)
+        x, ys = jax.lax.scan(body, x, (params["layers"], layer_ids))
         cache = {"k": ys[0], "v": ys[1]}
         if cfg.family == "encdec":
             cache["cross_k"], cache["cross_v"] = ys[2], ys[3]
-        return self._head(params, last_hidden(x))[:, 0, :cfg.vocab], cache
+        stats = jnp.sum(ys[2], axis=0) if cfg.family == "moe" else None
+        return (self._head(params, last_hidden(x))[:, 0, :cfg.vocab], cache,
+                stats)
 
     def _hybrid_decode(self, params, cache, x, pos,
                        attend_len: Optional[int] = None):

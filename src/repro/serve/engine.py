@@ -124,6 +124,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.decode_attention.decode_attention import pages_per_block
+from repro.models.moe import MOE_STATS
 from repro.serve import calibrate, sla, spec_decode
 from repro.serve.audit import audit_pool
 from repro.serve.faults import InjectedFault, KernelBackendError, poison_pages
@@ -164,6 +165,9 @@ COUNTERS = (
     "prefill_tokens",       # uncached prompt tokens computed
     "prefill_causal_keys",  # sum of n * start + n (n + 1) / 2
     "admissions",           # requests whose first token a prefill sampled
+    # expert layers (repro.models.moe.MOE_STATS), summed over layers and
+    # over prefills and decode steps; zero for a model without experts
+    *MOE_STATS,
 )
 
 # bounded-queue shed policies: who gets rejected when the waiting queue
@@ -246,11 +250,11 @@ class PendingRound:
 
 
 # families for which right-padded prefill is exact: cache purely positional
-# (mask-protected) AND no cross-token compute beyond causal attention.
-# Recurrent state (ssm/hybrid) advances through padding; MoE expert
-# capacity / GShard grouping depend on the padded length, so both admit
-# sequentially at batch 1 instead.
-_PADDED_PREFILL_FAMILIES = ("dense",)
+# (mask-protected) AND no cross-token compute beyond causal attention.  The
+# MoE serving layer is dropless and routes no padding row, so each token's
+# result is independent of the batch.  Recurrent state (ssm/hybrid)
+# advances through padding, so those admit sequentially at batch 1.
+_PADDED_PREFILL_FAMILIES = ("dense", "moe")
 
 
 def _home_device(params):
@@ -409,14 +413,13 @@ class ServeEngine:
                 raise ValueError("prefix sharing maps prompt prefixes "
                                  "through the paged block tables; pass "
                                  "cache_layout='paged'")
-            if model.cfg.family != "dense":
+            if model.cfg.family not in ("dense", "moe"):
                 raise ValueError(
                     "prefix sharing resolves prompts by token ids and "
                     "prefills only the un-cached suffix; family "
                     f"{model.cfg.family!r} prefills with non-positional "
-                    "state (frontend embeddings / length-dependent expert "
-                    "capacity), so cached K/V would not be exact — "
-                    "supported family: 'dense'")
+                    "state (frontend embeddings), so cached K/V would not "
+                    "be exact — supported families: 'dense', 'moe'")
         if num_pages is None:
             # capacity parity with the dense pool (+1 for the trash page)
             num_pages = batch_slots * cdiv(max_seq, page_size) + 1
@@ -484,9 +487,10 @@ class ServeEngine:
 
         def prefill_bucket_fn(params, batch, last_pos):
             # paged admission: the cache is scattered into pages, so pad
-            # only to the prompt bucket instead of all of max_seq
-            return model.prefill(params, batch, batch["tokens"].shape[1],
-                                 last_pos)
+            # only to the prompt bucket instead of all of max_seq; the
+            # third output is the expert layers' counts (None without)
+            return model._prefill(params, batch, batch["tokens"].shape[1],
+                                  last_pos)
 
         def decode_fn(params, cache, tokens, pos):
             logits, cache = model.decode_step(params, cache, tokens, pos)
@@ -520,10 +524,11 @@ class ServeEngine:
                           uids, nan_mask, attend_len):
             """Paged twin of fused_step_fn: the page pool is donated, the
             block tables are a read-only input (uploaded at allocator
-            boundaries, reused across steps)."""
+            boundaries, reused across steps).  Also returns the expert
+            layers' counts (None for a model without experts)."""
             cache = dict(pool, block_tables=block_tables)
-            logits, cache = model.decode_step(params, cache, tok, pos,
-                                              attend_len)
+            logits, cache, stats = model._gqa_decode_paged(
+                params, cache, tok, pos, attend_len)
             # rebuild generically: quantized pools carry k_scales/v_scales
             # alongside the value leaves, and the donated step must hand
             # all of them back
@@ -535,7 +540,7 @@ class ServeEngine:
             pos = pos + 1
             remaining = remaining - 1
             done = (remaining <= 0) | (pos >= max_seq - 1)
-            return pool, nxt, pos, remaining, done, bad
+            return pool, nxt, pos, remaining, done, bad, stats
 
         kw: Dict[str, Any] = {}
         fkw: Dict[str, Any] = {}
@@ -584,10 +589,10 @@ class ServeEngine:
                 logits come back.  The pool is donated — the suffix lands
                 in place like every other cache write."""
                 cache = dict(pool, block_tables=block_tables)
-                logits, cache = model.prefill_suffix(
+                logits, cache, stats = model.prefill_suffix(
                     params, cache, toks, start_pos, last_idx, attend_len,
                     vb)
-                return logits, {name: cache[name] for name in pool}
+                return logits, {name: cache[name] for name in pool}, stats
 
             self._suffix_prefill = jax.jit(suffix_prefill_fn,
                                            static_argnums=(6,),
@@ -1449,10 +1454,11 @@ class ServeEngine:
         attend = self._attend_len(needed)
         self._count_decode(st, 1, attend if self.fused else self.max_seq)
         nan_mask = self._nan_mask(st)
+        stats = None
         if self.fused and st.mgr is not None:
             self._upload_tables(st)
             (st.pool, st.tok, st.pos, st.remaining, done,
-             bad) = self._paged_step(
+             bad, stats) = self._paged_step(
                 self.params, st.pool, st.bt_dev, st.tok, st.pos,
                 st.remaining, st.uids, nan_mask, attend)
         elif self.fused:
@@ -1470,7 +1476,8 @@ class ServeEngine:
             st.remaining = st.remaining - 1
             st.tok = nxt
             done = (st.remaining <= 0) | (st.pos >= self.max_seq - 1)
-        return PendingRound(arrays=(st.tok, done, bad), live=dict(st.live))
+        return PendingRound(arrays=(st.tok, done, bad, stats),
+                            live=dict(st.live))
 
     def _dispatch_spec(self, st: "_SchedState") -> PendingRound:
         """Speculative twin of :meth:`_dispatch_step`: one dispatch
@@ -1539,15 +1546,23 @@ class ServeEngine:
                    else self._dispatch_step(st))
         self._commit_step(st, pending)
 
+    def _count_moe(self, st: "_SchedState", stats):
+        """Add a step's or a prefill's expert-layer counts (fetched with
+        its tokens; None for a model without experts)."""
+        if stats is not None:
+            for name, n in zip(MOE_STATS, stats):
+                st.counters[name] += int(n)
+
     def _commit_step(self, st: "_SchedState", pending: PendingRound):
         """The one host transfer per step — slot-count ints + flags (a
-        candidate window per slot when speculative) — then per-slot
-        token/terminal accounting over the slots that were live at
-        dispatch."""
+        candidate window per slot when speculative) and the expert
+        layers' counts — then per-slot token/terminal accounting over the
+        slots that were live at dispatch."""
         if pending.spec:
             return self._commit_spec(st, pending)
         with span(st, "serve.fetch"):
-            nxt_h, done_h, bad_h = jax.device_get(pending.arrays)
+            nxt_h, done_h, bad_h, stats_h = jax.device_get(pending.arrays)
+        self._count_moe(st, stats_h)
         with span(st, "serve.commit"):
             now = time.perf_counter() - st.t0
             for slot, req in list(pending.live.items()):
@@ -1846,17 +1861,19 @@ class ServeEngine:
         self._count_prefill(st, len(chunk), cs.done)
         with span(st, "serve.prefill", uid=req.uid, tokens=len(chunk),
                   bucket=t_b):
-            logits, st.pool = self._suffix_prefill(
+            logits, st.pool, stats = self._suffix_prefill(
                 self.params, st.pool, st.bt_dev[slot:slot + 1],
                 jnp.asarray(toks), jnp.asarray([cs.done], jnp.int32),
                 jnp.asarray([len(chunk) - 1], jnp.int32), attend)
         cs.done += len(chunk)
+        if stats is not None:   # the expert counts ride the last fetch
+            cs.stats = stats if cs.stats is None else cs.stats + stats
         s = st.stats[req.uid]
         s["prefill_chunks"] = s.get("prefill_chunks", 0) + 1
         if final:
             del st.prefilling[slot]
             st.live[slot] = req
-            self._commit_prefill(st, [slot], [req], logits)
+            self._commit_prefill(st, [slot], [req], logits, cs.stats)
             self._finish_admission(st, slot, req)
         return len(chunk)
 
@@ -1961,7 +1978,7 @@ class ServeEngine:
         self._count_prefill(st, len(suffix), plan.cached_tokens)
         with span(st, "serve.prefill", uid=req.uid, tokens=len(suffix),
                   bucket=t_b):
-            logits, st.pool = self._suffix_prefill(
+            logits, st.pool, stats = self._suffix_prefill(
                 self.params, st.pool, st.bt_dev[slot:slot + 1],
                 jnp.asarray(toks),
                 jnp.asarray([plan.cached_tokens], jnp.int32),
@@ -1978,14 +1995,15 @@ class ServeEngine:
                 self.draft_params, {"tokens": jnp.asarray(full)},
                 jnp.asarray([len(req.prompt) - 1], jnp.int32))
             st.draft_cache = write_slot(st.draft_cache, dcache, slot)
-        self._commit_prefill(st, [slot], [req], logits)
+        self._commit_prefill(st, [slot], [req], logits, stats)
 
     def _commit_prefill(self, st: "_SchedState", slots: List[int],
-                        reqs: List[Request], logits):
+                        reqs: List[Request], logits, stats=None):
         """Post-prefill slot-state commit, shared by the full and the
         suffix admission prefills (one implementation keeps the two paths
         behaviorally identical): sample each row's first token at
-        position ``len(prompt)`` with its (uid, position) key, scatter
+        position ``len(prompt)`` with its (uid, position) key (fetched
+        with the prefill's expert-layer ``stats``), scatter
         pos/tok/remaining/uids (+ spec flags) into the slot state, and
         append the sampled token.  Rows whose prefill logits are
         non-finite (numerical blowup, corrupted shared prefix) are
@@ -1996,8 +2014,10 @@ class ServeEngine:
                                             jnp.int32))
         finite = jnp.all(jnp.isfinite(logits), axis=-1)
         with span(st, "serve.prefill_fetch"):
-            first_h, finite_h = jax.device_get((first, finite))
+            first_h, finite_h, stats_h = jax.device_get(
+                (first, finite, stats))
         st.counters["admissions"] += len(reqs)
+        self._count_moe(st, stats_h)
         slot_idx = jnp.asarray(slots, jnp.int32)
         st.pos = st.pos.at[slot_idx].set(jnp.asarray(lens, jnp.int32))
         st.tok = st.tok.at[slot_idx].set(first)
@@ -2031,12 +2051,13 @@ class ServeEngine:
         slots = [s for s, _ in group]
         reqs = [r for _, r in group]
         lens = [len(r.prompt) for r in reqs]
+        stats = None
         if self.model.cfg.family in _PADDED_PREFILL_FAMILIES:
             bucket = min(self.max_seq,
                          _round_up(max(lens), self.prompt_block))
         else:
-            # right-padding perturbs recurrent state / MoE capacity;
-            # these families admit one request at its exact length
+            # right-padding perturbs recurrent state; these families
+            # admit one request at its exact length
             bucket = max(lens)
         toks = np.zeros((len(reqs), bucket), np.int32)
         for i, r in enumerate(reqs):
@@ -2049,7 +2070,7 @@ class ServeEngine:
                        tokens=sum(lens), bucket=bucket)
         if st.mgr is not None:
             with prefill:
-                logits, pcache = self._prefill_bucket(
+                logits, pcache, stats = self._prefill_bucket(
                     self.params, {"tokens": jnp.asarray(toks)}, last_pos)
             n_blocks = cdiv(bucket, self.page_size)
             page_idx = np.stack([st.mgr.prefill_page_idx(s, n_blocks)
@@ -2080,7 +2101,7 @@ class ServeEngine:
                 st.draft_cache = write_slots(
                     st.draft_cache, dcache, jnp.asarray(slots, jnp.int32))
         # the token sampled from prefill logits sits at position len(prompt)
-        self._commit_prefill(st, slots, reqs, logits)
+        self._commit_prefill(st, slots, reqs, logits, stats)
 
     # ----------------------------------------------------------- preemption
     def _grow_or_preempt(self, st: "_SchedState"):
@@ -2258,6 +2279,7 @@ class _ChunkState:
     prompt tokens written, not yet in the decode batch."""
     req: Request
     done: int = 0
+    stats: Any = None   # expert-layer counts of the chunks so far (device)
 
 
 def _empty_timeseries() -> Dict[str, list]:

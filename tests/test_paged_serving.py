@@ -157,8 +157,9 @@ def test_reserving_same_request_objects_is_fresh():
 
 
 def test_paged_moe_family_sequential_admission():
-    """MoE prefills at batch 1 (capacity depends on length) but still
-    serves through the paged pool."""
+    """MoE admits through padded batched prefill (the dropless expert
+    layer routes no padding row) and serves through the paged pool
+    exactly as through the dense layout."""
     reqs = _reqs(4, seed=5, arch="olmoe-1b-7b")
     want = _serve(_engine(arch="olmoe-1b-7b"), reqs)
     got = _serve(_engine(arch="olmoe-1b-7b", cache_layout="paged",

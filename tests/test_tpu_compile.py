@@ -1,7 +1,8 @@
 """Compile guards for the real chip: the main-path Pallas kernels at
 qwen2-1.5b widths (12 query heads, 2 KV heads, head dim 128, bf16,
-batch 8), the paged decode and verify kernels at the two benchmark
-configurations' own widths, and a two-layer paged decode step,
+batch 8), the paged decode and verify kernels at the benchmark
+configurations' own widths, a two-layer paged decode step, and the
+grouped matmul and a two-layer expert step at Qwen2-57B-A14B's widths,
 AOT-compiled for a described TPU v5e.  Interpret mode checks no tiling or
 fast-memory (VMEM) rule; the chip's compiler, installed here, does — so a
 refusal shows up in this file instead of on the chip.
@@ -23,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.decode_attention.ops import (decode_attention_op,
                                                 paged_decode_attention_op)
 from repro.kernels.flash_attention.ops import flash_mha
+from repro.kernels.grouped_matmul.ops import KERNEL_NAME, grouped_matmul_op
 from repro.kernels.rmsnorm.ops import rmsnorm_op
 from repro.kernels.verify_attention.ops import paged_verify_attention_op
 from repro.models.config import ModelConfig
@@ -147,6 +149,9 @@ SERVED = {
     "qwen1.5-32b-8L": dict(b=64, hq=40, hkv=8, pages=1000, bucket=5248,
                            d_model=5120, d_ff=27392, vocab=152064,
                            tied=False),
+    "qwen2-57b-a14b-7L": dict(b=128, hq=28, hkv=4, pages=1780, bucket=5248,
+                              d_model=3584, d_ff=2560, vocab=151936,
+                              tied=False),
 }
 
 
@@ -244,3 +249,62 @@ def test_paged_decode_step_copies_no_pool(one_chip, served, monkeypatch):
     text = step.lower(params, cache, ids, ids).compile().as_text()
     assert text.count("tpu_custom_call") >= 2
     assert _pool_copies(text, w) == []
+
+
+# Qwen2-57B-A14B's expert layer on one chip of four: 16 held experts of
+# d 3584 and f 2560, read from a stack of 7 layers
+EXPERTS, D_EXPERT, F_EXPERT, STAGE = 16, 3584, 2560, 7
+
+
+@pytest.mark.parametrize("k,n", [(D_EXPERT, F_EXPERT), (F_EXPERT, D_EXPERT)])
+@pytest.mark.parametrize("rows", [1024, 8192])
+def test_grouped_matmul_compiles_at_published_widths(one_chip, rows, k, n):
+    """The gate/up (d -> f) and down (f -> d) projections over a decode
+    step's (token, expert) rows (128 tokens x top 8) and a prefill's
+    (1024 x 8), at a traced layer of the stack."""
+    text = _compile(
+        lambda x, w, sizes, layer: grouped_matmul_op(x, w, sizes, layer,
+                                                     interpret=False),
+        one_chip, ((rows, k), jnp.bfloat16),
+        ((STAGE, EXPERTS, k, n), jnp.bfloat16), ((EXPERTS,), jnp.int32),
+        ((), jnp.int32))
+    assert "tpu_custom_call" in text and KERNEL_NAME in text
+
+
+def test_expert_step_copies_no_expert_weights(one_chip, monkeypatch):
+    """A two-layer MoE paged decode step at Qwen2-57B-A14B's widths: the
+    grouped matmul reads each layer's experts from the stack where they
+    live, so no instruction of the step materializes a layer's experts."""
+    import repro.kernels.common as common
+
+    monkeypatch.setattr(common, "default_interpret", lambda: False)
+    w = SERVED["qwen2-57b-a14b-7L"]
+    cfg = ModelConfig(name="qwen2-57b-a14b", family="moe", n_layers=2,
+                      d_model=D_EXPERT, n_heads=w["hq"], n_kv_heads=w["hkv"],
+                      d_head=D, d_ff=F_EXPERT, vocab=w["vocab"],
+                      qkv_bias=True, n_experts=64, top_k=8,
+                      experts_held=EXPERTS, shared_expert_ff=20480,
+                      norm_topk_prob=False)
+    model = Model(cfg, param_dtype=jnp.bfloat16,
+                  compute_dtype=jnp.bfloat16, decode_backend="kernel")
+    place = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: model.init_cache(
+        w["b"], w["bucket"], layout="paged", page_size=128,
+        num_pages=w["pages"])))
+    ids = jax.ShapeDtypeStruct((w["b"],), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, c, tok, pos: model._gqa_decode_paged(
+        p, c, tok, pos, attend_len=2048), donate_argnums=1)
+    text = step.lower(params, cache, ids, ids).compile().as_text()
+    assert text.count(KERNEL_NAME + " = ") + text.count(
+        KERNEL_NAME + ".") >= 6
+    entry = text[text.index("\nENTRY"):]
+    layer = {f"[{EXPERTS},{D_EXPERT},{F_EXPERT}]",
+             f"[{EXPERTS},{F_EXPERT},{D_EXPERT}]"}
+    copies = [line.strip()[:160] for line in
+              entry[:entry.index("\n}")].splitlines()
+              if any(shape in line for shape in layer)
+              and "parameter(" not in line and " bitcast(" not in line]
+    assert copies == []
